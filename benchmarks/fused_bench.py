@@ -30,6 +30,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from benchmarks.common import random_measure, timeit
 from repro.core import GWConfig, entropic_gw, entropic_gw_batch, fgc
 from repro.core.grids import Grid1D
+from repro.launch.compile_cache import use_compile_cache
 
 
 def bench_dtilde(ns=(256, 1024, 4096), ps=(1, 2), b=64):
@@ -93,6 +94,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="alias for --quick (CI executes the perf path)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.quick or args.smoke:
         dt = bench_dtilde(ns=(256, 1024), ps=(1, 2), b=16)
         bs = bench_batched(sizes=((32, 40), (40, 32), (24, 36), (40, 40)))

@@ -42,6 +42,7 @@ from repro.core import GradientOperator
 from repro.core.geometry import (GridGeometry, LowRankGeometry,
                                  PointCloudGeometry)
 from repro.core.grids import Grid1D
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _geometries(n: int, rank: int, rng):
@@ -116,6 +117,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes: execute the perf path in CI")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         ns, ranks = (64, 128), (4, 8)
     else:

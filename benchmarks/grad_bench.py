@@ -70,7 +70,10 @@ def _problem(n: int):
 def _run_case(mode: str, n: int) -> dict:
     import jax
 
+    from repro.launch.compile_cache import use_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    use_compile_cache()
     import jax.numpy as jnp
     import numpy as np
 

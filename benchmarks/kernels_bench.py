@@ -44,6 +44,7 @@ from repro.core import fgc
 from repro.core import sinkhorn as sk
 from repro.core.grids import Grid1D
 from repro.core.gw import GWConfig, entropic_gw
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(report):
@@ -129,6 +130,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="alias for --quick (CI executes the perf path)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.quick or args.smoke:
         sweep = bench_sinkhorn_sweep(sizes=(256, 512), iters=4, repeats=2)
         delta = bench_solver_delta(n=48, repeats=2)

@@ -64,7 +64,10 @@ def _on_tpu() -> bool:
 def _run_case(plan: str, n: int, backend: str) -> dict:
     import jax
 
+    from repro.launch.compile_cache import use_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    use_compile_cache()
     import jax.numpy as jnp
     import numpy as np
 

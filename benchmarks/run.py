@@ -36,6 +36,8 @@ def main():
     ap.add_argument("--only", default=None,
                     help="comma-separated table modules to run")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (kernels_bench, roofline, table2_1d, table3_2d,
                             table4_timeseries, table5_digits, table6_horse)

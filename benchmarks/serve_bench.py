@@ -70,21 +70,29 @@ import sys
 import time
 from pathlib import Path
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
-import jax.numpy as jnp
-import numpy as np
-
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from benchmarks.common import random_measure
-from repro.core import GWConfig
-from repro.core.grids import Grid1D
-from repro.serve.engine import GWEngine, GWServeConfig
-
 _REPO = Path(__file__).resolve().parent.parent
+
+
+def _load_solver_stack():
+    """Import JAX and the solver stack into this module's namespace — only
+    in a process that runs a case.  The ``--pipeline`` parent stays off JAX
+    while its child processes hold the device."""
+    global jax, jnp, np, random_measure, GWConfig, Grid1D, GWEngine, \
+        GWServeConfig
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.common import random_measure
+    from repro.core import GWConfig
+    from repro.core.grids import Grid1D
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.serve.engine import GWEngine, GWServeConfig
+
+    jax.config.update("jax_enable_x64", True)
+    use_compile_cache()
 
 EPS_CYCLE = [5e-2, 2e-2, 8e-3, 2e-3]    # easy → hard, interleaved
 
@@ -419,7 +427,7 @@ def pipeline_bench(args) -> dict:
                   f"{c['peak_rss_mb_after_copying_flush']:.0f} MB",
                   flush=True)
     return {
-        "backend": jax.default_backend(), "smoke": bool(args.smoke),
+        "backend": cases["stream"]["backend"], "smoke": bool(args.smoke),
         "cases": cases,
         "summary": {
             "wall_speedup_vs_continuous": cases["stream"]["wall_speedup"],
@@ -447,7 +455,9 @@ def main():
                          "print its JSON")
     args = ap.parse_args()
     if args.case:
-        print(json.dumps(_PIPELINE_CASES[args.case](args.smoke)))
+        _load_solver_stack()
+        print(json.dumps({**_PIPELINE_CASES[args.case](args.smoke),
+                          "backend": jax.default_backend()}))
         return 0
     if args.pipeline:
         out = pipeline_bench(args)
@@ -455,6 +465,7 @@ def main():
         Path(dest).write_text(json.dumps(out, indent=2) + "\n")
         print(f"wrote {dest}")
         return 0 if out["summary"]["acceptance"] or args.smoke else 1
+    _load_solver_stack()
     n = args.n or (16 if args.smoke else 64)
     n_req = args.requests or (6 if args.smoke else 24)
     out = bench(n, n_req, args.smoke)

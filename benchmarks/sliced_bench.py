@@ -51,6 +51,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from repro.core import GWConfig, entropic_gw
 from repro.core.geometry import PointCloudGeometry
 from repro.core.sliced import _sliced_core, sliced_gw
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.engine import GWEngine, GWServeConfig
 
 _REPO = Path(__file__).resolve().parent.parent
@@ -311,6 +312,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes: execute every path in CI")
     args = ap.parse_args()
+    use_compile_cache()
 
     cases = {}
     for name, fn in (("latency", case_latency), ("cache", case_cache),

@@ -49,6 +49,7 @@ from benchmarks.common import random_measure, timeit
 from repro.core import GWConfig, SolveControls, entropic_gw
 from repro.core.geometry import PointCloudGeometry
 from repro.core.grids import Grid1D, Grid2D
+from repro.launch.compile_cache import use_compile_cache
 
 
 FIXED = dict(outer_iters=10, sinkhorn_iters=200)          # paper §4.1
@@ -183,6 +184,7 @@ def main():
                     help="tiny sizes: execute the perf path in CI")
     ap.add_argument("--n", type=int, default=None, help="problem size")
     args = ap.parse_args()
+    use_compile_cache()
     n = args.n or (24 if args.smoke else 64)
     out = bench(n, args.smoke)
     Path(args.out).write_text(json.dumps(out, indent=2) + "\n")

@@ -65,18 +65,24 @@ def _fgc_kernel(x_ref, l_ref, v_ref, pr_ref, t_ref, y_ref, acc_ref, *,
     y_ref[...] = y
 
 
-def _dtilde_kernel(x_ref, xm_ref, l_ref, v_ref, pr_ref, t_ref,
-                   ylo_ref, yhi_ref, a_ref, b_ref, *, p: int,
+def _dtilde_kernel(x_ref, xm_ref, l_ref, v_ref, pr_ref, t_ref, lt_ref,
+                   vr_ref, tr_ref, ylo_ref, yhi_ref, a_ref, b_ref, *, p: int,
                    block_rows: int):
     """Fused D̃ = L + Lᵀ step: ONE sequential row-block sweep.
 
     At row step r the kernel sees block r of x (forward stream) and block
     nrb−1−r (mirror stream).  The forward stream runs the L recursion into
-    output block r; the mirror stream, row-reversed, is block r of the
-    reversed sequence x̃ — running the SAME L recursion on it and
-    row-reversing the result yields output block nrb−1−r of Lᵀx
-    (Lᵀx = flip(L x̃)).  Two (p+1)-moment states live in VMEM scratch; the
-    final D̃x is the sum of the two outputs (done outside the kernel).
+    output block r.  The mirror stream, row-reversed (J xm, J the R×R
+    anti-identity), is block r of the reversed sequence x̃; the SAME L
+    recursion on it, row-reversed, is output block nrb−1−r of Lᵀx
+    (Lᵀx = flip(L x̃)).  Both reversals are folded into constants
+    (J L J = L_Rᵀ, J V, T J), because the TPU lowering has no in-kernel
+    row reversal:
+
+        yhi = L_Rᵀ xm + (J V) b        b' = P_R b + (T J) xm
+
+    Two (p+1)-moment states live in VMEM scratch; the final D̃x is the sum
+    of the two outputs (done outside the kernel).
     """
     dtype = x_ref.dtype
     row_idx = pl.program_id(1)
@@ -86,21 +92,17 @@ def _dtilde_kernel(x_ref, xm_ref, l_ref, v_ref, pr_ref, t_ref,
         a_ref[...] = jnp.zeros_like(a_ref)
         b_ref[...] = jnp.zeros_like(b_ref)
 
+    def dot(u, w):
+        return jnp.dot(u, w, preferred_element_type=dtype)
+
     x = x_ref[...]
-    xr = xm_ref[...][::-1]
+    xm = xm_ref[...]
     a = a_ref[...]
     b = b_ref[...]
-    l_r = l_ref[...]
-    v = v_ref[...]
-    ylo_ref[...] = (jnp.dot(l_r, x, preferred_element_type=dtype)
-                    + jnp.dot(v, a, preferred_element_type=dtype))
-    z = (jnp.dot(l_r, xr, preferred_element_type=dtype)
-         + jnp.dot(v, b, preferred_element_type=dtype))
-    yhi_ref[...] = z[::-1]
-    a_ref[...] = (jnp.dot(pr_ref[...], a, preferred_element_type=dtype)
-                  + jnp.dot(t_ref[...], x, preferred_element_type=dtype))
-    b_ref[...] = (jnp.dot(pr_ref[...], b, preferred_element_type=dtype)
-                  + jnp.dot(t_ref[...], xr, preferred_element_type=dtype))
+    ylo_ref[...] = dot(l_ref[...], x) + dot(v_ref[...], a)
+    yhi_ref[...] = dot(lt_ref[...], xm) + dot(vr_ref[...], b)
+    a_ref[...] = dot(pr_ref[...], a) + dot(t_ref[...], x)
+    b_ref[...] = dot(pr_ref[...], b) + dot(tr_ref[...], xm)
 
 
 @functools.partial(jax.jit,
@@ -120,6 +122,7 @@ def fgc_apply_dtilde_pallas(x, p: int = 1, block_rows: int = BLOCK_ROWS,
     nrb = np_ // block_rows
     grid = (bp_ // LANES, nrb)  # rows innermost => sequential
     l_r, v, p_r, t = _block_constants(p, block_rows, dtype)
+    consts = (l_r, v, p_r, t, l_r.T, v[::-1], t[:, ::-1])
 
     def _const_spec(arr):
         return pl.BlockSpec(arr.shape, lambda c, r: (0,) * arr.ndim)
@@ -132,15 +135,14 @@ def fgc_apply_dtilde_pallas(x, p: int = 1, block_rows: int = BLOCK_ROWS,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda c, r: (r, c)),
                   pl.BlockSpec((block_rows, LANES),
                                lambda c, r: (nrb - 1 - r, c)),
-                  _const_spec(l_r), _const_spec(v), _const_spec(p_r),
-                  _const_spec(t)],
+                  *(_const_spec(k) for k in consts)],
         out_specs=[pl.BlockSpec((block_rows, LANES), lambda c, r: (r, c)),
                    pl.BlockSpec((block_rows, LANES),
                                 lambda c, r: (nrb - 1 - r, c))],
         scratch_shapes=[pltpu.VMEM((p + 1, LANES), dtype),
                         pltpu.VMEM((p + 1, LANES), dtype)],
         interpret=interpret,
-    )(xp, xp, l_r, v, p_r, t)
+    )(xp, xp, *consts)
     return (y_lo + y_hi)[:n, :b]
 
 

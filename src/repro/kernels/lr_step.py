@@ -58,8 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.sinkhorn_step import (BM, _cast_cost, _finish_lse,
-                                         _online_lse_update,
+from repro.kernels.sinkhorn_step import (BM, _cast_cost, _col, _finish_lse,
+                                         _online_lse_update, _row,
                                          default_interpret)
 
 #: rank/cost lane tile — factor ranks are small (8..64), one 128-lane tile
@@ -89,23 +89,24 @@ def _dykstra_half_kernel(lk_ref, gcol_ref, logw_ref, f_ref, col_ref,
 
     # astype upcasts bf16 kernel tiles (cost_dtype="bf16"); no-op otherwise
     lk = lk_ref[...].astype(gcol_ref.dtype)                # (BM, RP)
-    z = gcol_ref[...][None, :] + lk
+    z = gcol_ref[...] + lk
     # row-LSE over the rank lanes (−inf-padded): matches jax.scipy's
     # logsumexp — amax + log Σ exp(z − amax), all-(−inf) rows pinned to −inf
-    m1 = jnp.max(z, axis=1)
-    e = jnp.where(jnp.isfinite(m1)[:, None], jnp.exp(z - m1[:, None]), 0.0)
-    lse1 = jnp.where(jnp.isfinite(m1), m1 + jnp.log(jnp.sum(e, axis=1)),
+    m1 = jnp.max(z, axis=1, keepdims=True)                 # (BM, 1)
+    e = jnp.where(jnp.isfinite(m1), jnp.exp(z - m1), 0.0)
+    lse1 = jnp.where(jnp.isfinite(m1),
+                     m1 + jnp.log(jnp.sum(e, axis=1, keepdims=True)),
                      -jnp.inf)
     logw = logw_ref[...]
     f = jnp.where(logw > -jnp.inf, logw - lse1, -jnp.inf)
     f_ref[...] = f
     # fold the SAME block into the column LSE at the NEW f — exactly the
     # value the XLA sweep computes from (f_new, lk) in its second pass
-    _online_lse_update(f[:, None] + lk, m_ref, s_ref, axis=0)
+    _online_lse_update(f + lk, m_ref, s_ref, axis=0)
 
     @pl.when(i == n_row_blocks - 1)
     def _finish():
-        col_ref[...] = _finish_lse(m_ref[...][0, :], s_ref[...][0, :])
+        col_ref[...] = _finish_lse(m_ref[...], s_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "cost_dtype"))
@@ -127,28 +128,28 @@ def lr_dykstra_half_pallas(lk, gcol, logw, interpret: bool | None = None,
     dtype = lk.dtype
     lkp = _pad_axis(_pad_axis(lk, 0, BM, -jnp.inf), 1, BR, -jnp.inf)
     lkp = _cast_cost(lkp, cost_dtype)
-    gp = _pad_axis(gcol, 0, BR, 0.0)
-    logwp = _pad_axis(logw, 0, BM, -jnp.inf)
+    gp = _row(_pad_axis(gcol, 0, BR, 0.0))
+    logwp = _col(_pad_axis(logw, 0, BM, -jnp.inf))
     rp = lkp.shape[1]
     grid = (lkp.shape[0] // BM,)
 
     f, col = pl.pallas_call(
         functools.partial(_dykstra_half_kernel, n_row_blocks=grid[0]),
-        out_shape=(jax.ShapeDtypeStruct((lkp.shape[0],), dtype),
-                   jax.ShapeDtypeStruct((rp,), dtype)),
+        out_shape=(jax.ShapeDtypeStruct((lkp.shape[0], 1), dtype),
+                   jax.ShapeDtypeStruct((1, rp), dtype)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BM, rp), lambda i: (i, 0)),
-            pl.BlockSpec((rp,), lambda i: (0,)),
-            pl.BlockSpec((BM,), lambda i: (i,)),
+            pl.BlockSpec((1, rp), lambda i: (0, 0)),
+            pl.BlockSpec((BM, 1), lambda i: (i, 0)),
         ],
-        out_specs=(pl.BlockSpec((BM,), lambda i: (i,)),
-                   pl.BlockSpec((rp,), lambda i: (0,))),
+        out_specs=(pl.BlockSpec((BM, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((1, rp), lambda i: (0, 0))),
         scratch_shapes=[pltpu.VMEM((1, rp), dtype),
                         pltpu.VMEM((1, rp), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
     )(lkp, gp, logwp)
-    return f[:n], col[:r]
+    return f[:n, 0], col[0, :r]
 
 
 def lr_dykstra_half_pallas_batched(lk, gcol, logw,
@@ -196,7 +197,7 @@ def _gram_chain_kernel(a_ref, b_ref, q_ref, w_ref,
     def _accumulate_first_pass():
         bq_acc[...] += _dot_t(b_ref[...], q)               # BᵀQ   (CP, RP)
         sq_acc[...] += jnp.sum(q, axis=0)[None, :]
-        tq_acc[...] += _dot_t(w_ref[...][:, None], q)      # wᵀQ   (1, RP)
+        tq_acc[...] += _dot_t(w_ref[...], q)               # wᵀQ   (1, RP)
 
     @pl.when(phase == 1)
     def _accumulate_gram():
@@ -207,8 +208,8 @@ def _gram_chain_kernel(a_ref, b_ref, q_ref, w_ref,
     def _finish():
         bq_out[...] = bq_acc[...]
         gram_out[...] = gram_acc[...]
-        sq_out[...] = sq_acc[...][0, :]
-        tq_out[...] = tq_acc[...][0, :]
+        sq_out[...] = sq_acc[...]
+        tq_out[...] = tq_acc[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -229,7 +230,7 @@ def lr_gram_chain_pallas(a_fac, b_fac, q, w, interpret: bool | None = None):
     ap = _pad_axis(_pad_axis(a_fac, 0, BM, 0.0), 1, BR, 0.0)
     bp = _pad_axis(_pad_axis(b_fac, 0, BM, 0.0), 1, BR, 0.0)
     qp = _pad_axis(_pad_axis(q, 0, BM, 0.0), 1, BR, 0.0)
-    wp = _pad_axis(w, 0, BM, 0.0)
+    wp = _col(_pad_axis(w, 0, BM, 0.0))
     cp, rp = ap.shape[1], qp.shape[1]
     nb = ap.shape[0] // BM
     grid = (2, nb)
@@ -238,26 +239,26 @@ def lr_gram_chain_pallas(a_fac, b_fac, q, w, interpret: bool | None = None):
         functools.partial(_gram_chain_kernel, n_row_blocks=nb),
         out_shape=(jax.ShapeDtypeStruct((cp, rp), dtype),
                    jax.ShapeDtypeStruct((rp, rp), dtype),
-                   jax.ShapeDtypeStruct((rp,), dtype),
-                   jax.ShapeDtypeStruct((rp,), dtype)),
+                   jax.ShapeDtypeStruct((1, rp), dtype),
+                   jax.ShapeDtypeStruct((1, rp), dtype)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BM, cp), lambda p, i: (i, 0)),
             pl.BlockSpec((BM, cp), lambda p, i: (i, 0)),
             pl.BlockSpec((BM, rp), lambda p, i: (i, 0)),
-            pl.BlockSpec((BM,), lambda p, i: (i,)),
+            pl.BlockSpec((BM, 1), lambda p, i: (i, 0)),
         ],
         out_specs=(pl.BlockSpec((cp, rp), lambda p, i: (0, 0)),
                    pl.BlockSpec((rp, rp), lambda p, i: (0, 0)),
-                   pl.BlockSpec((rp,), lambda p, i: (0,)),
-                   pl.BlockSpec((rp,), lambda p, i: (0,))),
+                   pl.BlockSpec((1, rp), lambda p, i: (0, 0)),
+                   pl.BlockSpec((1, rp), lambda p, i: (0, 0))),
         scratch_shapes=[pltpu.VMEM((cp, rp), dtype),
                         pltpu.VMEM((rp, rp), dtype),
                         pltpu.VMEM((1, rp), dtype),
                         pltpu.VMEM((1, rp), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
     )(ap, bp, qp, wp)
-    return bq[:c, :r], gram[:r, :r], sq[:r], tq[:r]
+    return bq[:c, :r], gram[:r, :r], sq[0, :r], tq[0, :r]
 
 
 def lr_gram_chain_pallas_batched(a_fac, b_fac, q, w,
@@ -275,10 +276,8 @@ def lr_gram_chain_pallas_batched(a_fac, b_fac, q, w,
 def _grad_combine_kernel(a_ref, d2_ref, w_ref, s_ref, t_ref, iq_ref,
                          out_ref):
     quad = _dot(a_ref[...], w_ref[...])                    # (BM, RP)
-    d2 = d2_ref[...]
-    out_ref[...] = (2.0 * (d2[:, None] * s_ref[...][None, :]
-                           + t_ref[...][None, :])
-                    - 4.0 * quad) * iq_ref[...][None, :]
+    out_ref[...] = (2.0 * (d2_ref[...] * s_ref[...] + t_ref[...])
+                    - 4.0 * quad) * iq_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -297,11 +296,11 @@ def lr_grad_combine_pallas(a_fac, w_small, d2, s_other, t_other, iq,
     r = iq.shape[0]
     dtype = iq.dtype
     ap = _pad_axis(_pad_axis(a_fac, 0, BM, 0.0), 1, BR, 0.0)
-    d2p = _pad_axis(d2, 0, BM, 0.0)
-    sp = _pad_axis(s_other, 0, BR, 0.0)
-    tp = _pad_axis(t_other, 0, BR, 0.0)
-    iqp = _pad_axis(iq, 0, BR, 0.0)
-    cp, rp = ap.shape[1], iqp.shape[0]
+    d2p = _col(_pad_axis(d2, 0, BM, 0.0))
+    sp = _row(_pad_axis(s_other, 0, BR, 0.0))
+    tp = _row(_pad_axis(t_other, 0, BR, 0.0))
+    iqp = _row(_pad_axis(iq, 0, BR, 0.0))
+    cp, rp = ap.shape[1], iqp.shape[1]
     # w_small rows live on the cost axis: pad to the a-block lane width
     wp = _pad_axis(_pad_axis(w_small, 0, cp, 0.0), 1, BR, 0.0)
     grid = (ap.shape[0] // BM,)
@@ -312,11 +311,11 @@ def lr_grad_combine_pallas(a_fac, w_small, d2, s_other, t_other, iq,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BM, cp), lambda i: (i, 0)),
-            pl.BlockSpec((BM,), lambda i: (i,)),
+            pl.BlockSpec((BM, 1), lambda i: (i, 0)),
             pl.BlockSpec((cp, rp), lambda i: (0, 0)),
-            pl.BlockSpec((rp,), lambda i: (0,)),
-            pl.BlockSpec((rp,), lambda i: (0,)),
-            pl.BlockSpec((rp,), lambda i: (0,)),
+            pl.BlockSpec((1, rp), lambda i: (0, 0)),
+            pl.BlockSpec((1, rp), lambda i: (0, 0)),
+            pl.BlockSpec((1, rp), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((BM, rp), lambda i: (i, 0)),
         interpret=default_interpret() if interpret is None else interpret,

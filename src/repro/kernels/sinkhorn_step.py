@@ -16,8 +16,12 @@ written on the last reduction step.
 
 ε is a TRACED scalar operand delivered through SMEM — ε-annealing (a new ε
 every outer stage) and `SolveControls` retuning reuse one compiled
-executable instead of recompiling per stage.  The kernel divides by ε
-exactly as the XLA path does (`(g − C)/ε`, not a reciprocal multiply).
+executable instead of recompiling per stage.  It is a (1, 1) block: under
+vmap the operand becomes (B, 1, 1), whose last two block dims still equal
+the array's, as the TPU lowering requires (a (1,) block would become a
+(B, 1) array with a squeezed, non-full second-to-last dim).  The kernel
+divides by ε exactly as the XLA path does (`(g − C)/ε`, not a reciprocal
+multiply).
 Parity vs `jax.scipy` logsumexp is ≤1 ulp per half-step, not bitwise: the
 +inf-padded 128-wide tile sums (and, across tiles, the online
 renormalization) associate the reduction differently than XLA's unpadded
@@ -70,15 +74,13 @@ def _online_lse_update(z, m_ref, s_ref, axis: int):
     and one NaN would otherwise poison the running sum for good.  Once the
     max is finite the guards select the untouched fast path bit-for-bit.
     """
-    keep = (slice(None), 0) if axis == 1 else (0, slice(None))
-    m_old = m_ref[...][keep]
-    m_new = jnp.maximum(m_old, jnp.max(z, axis=axis))
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(z, axis=axis, keepdims=True))
     scale = jnp.where(jnp.isfinite(m_old), jnp.exp(m_old - m_new), 0.0)
-    m_b = m_new[:, None] if axis == 1 else m_new[None, :]
-    contrib = jnp.where(jnp.isfinite(m_b), jnp.exp(z - m_b), 0.0)
-    s_new = s_ref[...][keep] * scale + jnp.sum(contrib, axis=axis)
-    m_ref[...] = m_new[:, None] if axis == 1 else m_new[None, :]
-    s_ref[...] = s_new[:, None] if axis == 1 else s_new[None, :]
+    contrib = jnp.where(jnp.isfinite(m_new), jnp.exp(z - m_new), 0.0)
+    s_ref[...] = (s_ref[...] * scale
+                  + jnp.sum(contrib, axis=axis, keepdims=True))
+    m_ref[...] = m_new
 
 
 def _finish_lse(m, s):
@@ -90,7 +92,7 @@ def _finish_lse(m, s):
 def _row_kernel(eps_ref, cost_ref, g_ref, logmu_ref, f_ref, m_ref, s_ref, *,
                 n_col_blocks: int):
     col = pl.program_id(1)
-    eps = eps_ref[0]
+    eps = eps_ref[0, 0]
 
     @pl.when(col == 0)
     def _init():
@@ -100,43 +102,57 @@ def _row_kernel(eps_ref, cost_ref, g_ref, logmu_ref, f_ref, m_ref, s_ref, *,
     # divide (not reciprocal-multiply) so interpret mode matches the XLA
     # path's (g − C)/ε rounding bit-for-bit; the astype upcasts bf16 cost
     # tiles (cost_dtype="bf16") and is a no-op at matching dtypes
-    z = (g_ref[...][None, :]
+    z = (g_ref[...]
          - cost_ref[...].astype(g_ref.dtype)) / eps        # (BM, BN)
     _online_lse_update(z, m_ref, s_ref, axis=1)
 
     @pl.when(col == n_col_blocks - 1)
     def _finish():
-        lse = _finish_lse(m_ref[...][:, 0], s_ref[...][:, 0])
+        lse = _finish_lse(m_ref[...], s_ref[...])           # (BM, 1)
         f_ref[...] = eps * (logmu_ref[...] - lse)
 
 
 def _col_kernel(eps_ref, cost_ref, f_ref, lognu_ref, g_ref, m_ref, s_ref, *,
                 n_row_blocks: int):
     row = pl.program_id(1)
-    eps = eps_ref[0]
+    eps = eps_ref[0, 0]
 
     @pl.when(row == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    z = (f_ref[...][:, None]
+    z = (f_ref[...]
          - cost_ref[...].astype(f_ref.dtype)) / eps        # (BM, BN)
     _online_lse_update(z, m_ref, s_ref, axis=0)
 
     @pl.when(row == n_row_blocks - 1)
     def _finish():
-        lse = _finish_lse(m_ref[...][0, :], s_ref[...][0, :])
+        lse = _finish_lse(m_ref[...], s_ref[...])           # (1, BN)
         g_ref[...] = eps * (lognu_ref[...] - lse)
+
+
+# Vector operands travel as 2-D blocks: Mosaic tiles a 1-D f32 block by 128
+# while XLA tiles a 1-D f32 array by 1024, so (BM,) blocks are refused on the
+# TPU.  Column-indexed vectors are lane-dense (1, N) rows, row-indexed ones
+# (M, 1) columns — the orientations the kernels broadcast them in.
+def _row(v):
+    return v.reshape(1, -1)
+
+
+def _col(v):
+    return v.reshape(-1, 1)
 
 
 def _pad_operands(cost, v, w, bm: int, bn: int):
     """Pad C to (⌈M/BM⌉·BM, ⌈N/BN⌉·BN) with +inf — exp((· − inf)/ε) = 0, so
-    padded cells never contribute — and the vectors with zeros."""
+    padded cells never contribute — and the vectors with zeros; the
+    column-indexed ``v`` comes back as a (1, N) row, the row-indexed ``w``
+    as an (M, 1) column."""
     m, n = cost.shape
     mp, np_ = -m % bm, -n % bn
     costp = jnp.pad(cost, ((0, mp), (0, np_)), constant_values=jnp.inf)
-    return costp, jnp.pad(v, (0, np_)), jnp.pad(w, (0, mp))
+    return costp, _row(jnp.pad(v, (0, np_))), _col(jnp.pad(w, (0, mp)))
 
 
 def _cast_cost(costp, cost_dtype: str):
@@ -165,24 +181,25 @@ def sinkhorn_row_update_pallas(cost, g, log_mu, eps,
     costp, gp, logmup = _pad_operands(cost, g, log_mu, BM, BN)
     costp = _cast_cost(costp, cost_dtype)
     grid = (costp.shape[0] // BM, costp.shape[1] // BN)
-    eps_arr = jnp.asarray(eps, dtype).reshape((1,))
+    eps_arr = jnp.asarray(eps, dtype).reshape((1, 1))
 
     f = pl.pallas_call(
         functools.partial(_row_kernel, n_col_blocks=grid[1]),
-        out_shape=jax.ShapeDtypeStruct((costp.shape[0],), dtype),
+        out_shape=jax.ShapeDtypeStruct((costp.shape[0], 1), dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda r, c: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1), lambda r, c: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((BM, BN), lambda r, c: (r, c)),
-            pl.BlockSpec((BN,), lambda r, c: (c,)),
-            pl.BlockSpec((BM,), lambda r, c: (r,)),
+            pl.BlockSpec((1, BN), lambda r, c: (0, c)),
+            pl.BlockSpec((BM, 1), lambda r, c: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((BM,), lambda r, c: (r,)),
+        out_specs=pl.BlockSpec((BM, 1), lambda r, c: (r, 0)),
         scratch_shapes=[pltpu.VMEM((BM, 1), dtype),
                         pltpu.VMEM((BM, 1), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
     )(eps_arr, costp, gp, logmup)
-    return f[:m]
+    return f[:m, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "cost_dtype"))
@@ -197,24 +214,25 @@ def sinkhorn_col_update_pallas(cost, f, log_nu, eps,
     costp, lognup, fp = _pad_operands(cost, log_nu, f, BM, BN)
     costp = _cast_cost(costp, cost_dtype)
     grid = (costp.shape[1] // BN, costp.shape[0] // BM)
-    eps_arr = jnp.asarray(eps, dtype).reshape((1,))
+    eps_arr = jnp.asarray(eps, dtype).reshape((1, 1))
 
     g = pl.pallas_call(
         functools.partial(_col_kernel, n_row_blocks=grid[1]),
-        out_shape=jax.ShapeDtypeStruct((costp.shape[1],), dtype),
+        out_shape=jax.ShapeDtypeStruct((1, costp.shape[1]), dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda c, r: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1), lambda c, r: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((BM, BN), lambda c, r: (r, c)),
-            pl.BlockSpec((BM,), lambda c, r: (r,)),
-            pl.BlockSpec((BN,), lambda c, r: (c,)),
+            pl.BlockSpec((BM, 1), lambda c, r: (r, 0)),
+            pl.BlockSpec((1, BN), lambda c, r: (0, c)),
         ],
-        out_specs=pl.BlockSpec((BN,), lambda c, r: (c,)),
+        out_specs=pl.BlockSpec((1, BN), lambda c, r: (0, c)),
         scratch_shapes=[pltpu.VMEM((1, BN), dtype),
                         pltpu.VMEM((1, BN), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
     )(eps_arr, costp, fp, lognup)
-    return g[:n]
+    return g[0, :n]
 
 
 def _batched(fn, cost, v, w, eps, interpret, cost_dtype):

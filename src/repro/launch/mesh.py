@@ -14,13 +14,12 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.compat import axis_types_kwargs
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -28,10 +27,11 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     (``--mesh 8x4 --axes data,model``)."""
     assert int(np.prod(shape)) == len(jax.devices()), (
         shape, len(jax.devices()))
-    return jax.make_mesh(shape, axes, **axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def local_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many devices this process sees (tests)."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **axis_types_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

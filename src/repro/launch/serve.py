@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import jax
@@ -27,6 +28,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.serve.engine import (Engine, GWEngine, GWServeConfig, ServeConfig,
                                 run_event_loop)
@@ -60,9 +62,11 @@ def _gw_stream(n_requests: int, repeat_frac: float, seed: int):
         yield prob
 
 
-def gw_main(args) -> None:
+def gw_main(args) -> int:
     """Drive `GWEngine.serve` over the synthetic stream and report the
-    pipeline/cache telemetry the engine collected."""
+    pipeline/cache telemetry the engine collected.  Returns the exit code:
+    1 when any bucket failed (the engine isolates a failing bucket and
+    serves the rest, so a partial result alone would hide the failure)."""
     from repro.core.gw import GWConfig
 
     solver = GWConfig(eps=2e-1, outer_iters=60, sinkhorn_iters=200,
@@ -90,9 +94,9 @@ def gw_main(args) -> None:
           f"{s['cache_warm_starts']}/{s['cache_misses']} "
           f"(profile={s['cache_profile_hits']}) "
           f"sliced_answers={s['sliced_answers']}")
-    if engine.last_errors:
-        print(f"{len(engine.last_errors)} bucket failures: "
-              f"{[k for k, _ in engine.last_errors]}")
+    for key, exc in engine.last_errors:
+        print(f"bucket {key} failed: {exc!r}", file=sys.stderr)
+    return 1 if engine.last_errors else 0
 
 
 def main(argv=None):
@@ -123,10 +127,10 @@ def main(argv=None):
                     help="answer class: full solve, O(N log N) sliced "
                          "estimate, or sliced-then-refined")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.gw:
-        gw_main(args)
-        return
+        return gw_main(args)
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
@@ -152,7 +156,8 @@ def main(argv=None):
         print(f"request {i}: {row.tolist()}")
     print(f"{args.batch * args.max_new} tokens in {dt:.2f}s "
           f"({args.batch * args.max_new / dt:.1f} tok/s)")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

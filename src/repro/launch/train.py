@@ -23,6 +23,7 @@ from repro.checkpoint.manager import CheckpointManager, \
 from repro.data import pipeline
 from repro.distributed import sharding
 from repro.distributed.fault_tolerance import Heartbeat
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import local_mesh
 from repro.train import loop as train_loop
 from repro.train import optimizer as optim
@@ -51,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--data-path", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))

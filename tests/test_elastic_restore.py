@@ -29,8 +29,8 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.models import lm
 
 cfg = dataclasses.replace(configs.get_smoke("olmo-1b"), dtype="float32")
-from repro.compat import axis_types_kwargs
-mesh = jax.make_mesh((2, 2), ("data", "model"), **axis_types_kwargs(2))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 params = lm.init_params(jax.random.PRNGKey(7), cfg)
 from repro.distributed import sharding
 specs = sharding.param_specs(params, mesh)
@@ -55,8 +55,8 @@ from repro.distributed import sharding
 from repro.models import lm
 
 cfg = dataclasses.replace(configs.get_smoke("olmo-1b"), dtype="float32")
-from repro.compat import axis_types_kwargs
-mesh = jax.make_mesh((2, 4), ("data", "model"), **axis_types_kwargs(2))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 like = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
 specs = sharding.param_specs(like, mesh)
 shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

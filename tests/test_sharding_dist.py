@@ -107,8 +107,8 @@ from repro.train import loop as train_loop
 
 cfg = dataclasses.replace(configs.get_smoke("smollm-360m"), dtype="float32")
 tcfg = train_loop.TrainConfig(microbatches=1, remat=True)
-from repro.compat import axis_types_kwargs
-mesh = jax.make_mesh((4, 2), ("data", "model"), **axis_types_kwargs(2))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 state = jax.eval_shape(lambda: train_loop.init_state(
     jax.random.PRNGKey(0), cfg, tcfg))
 batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32),
