@@ -19,8 +19,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import sinkhorn as sk
 from repro.core.coupling import FullCoupling, coupling_delta, full_init
 from repro.core.geometry import as_geometry
@@ -58,11 +60,13 @@ def fgw_step_fn(op: GradientOperator, c2, theta, mu, nu, cfg: FGWConfig):
     step body behind the one-shot, batched, and segmented solves."""
 
     def step(state, eps, inner_tol):
-        grad = c2 - 4.0 * theta * op.product(state.plan)
-        gamma, f, g, err, used = sk.solve_adaptive(
-            grad, mu, nu, eps, cfg.sinkhorn_iters, cfg.sinkhorn_chunk,
-            inner_tol, cfg.sinkhorn_mode, state.f, state.g,
-            backend=cfg.sinkhorn_backend, cost_dtype=cfg.cost_dtype)
+        with jax.named_scope(scopes.GRAD):
+            grad = c2 - 4.0 * theta * op.product(state.plan)
+        with jax.named_scope(scopes.SINKHORN):
+            gamma, f, g, err, used = sk.solve_adaptive(
+                grad, mu, nu, eps, cfg.sinkhorn_iters, cfg.sinkhorn_chunk,
+                inner_tol, cfg.sinkhorn_mode, state.f, state.g,
+                backend=cfg.sinkhorn_backend, cost_dtype=cfg.cost_dtype)
         return FullCoupling(gamma, f, g), err, used
 
     return step
@@ -82,19 +86,21 @@ def fgw_lr_step_fn(op: LowRankGradientOperator, dx2, dy2, fsq, theta,
     and all solver state stay factored."""
 
     def step(state, eps, inner_tol):
-        gq, gr, gg = op.grads(state, dx2, dy2, cfg.g_floor)
-        iq = 1.0 / jnp.maximum(state.g, cfg.g_floor)
-        fr = fsq @ state.r       # (M, r)
-        fq = fsq.T @ state.q     # (N, r)
-        lin_diag = jnp.sum(state.q * fr, axis=0)        # diag(Qᵀ C² R)
-        gq = theta * gq + (1.0 - theta) * fr * iq[None, :]
-        gr = theta * gr + (1.0 - theta) * fq * iq[None, :]
-        gg = theta * gg - (1.0 - theta) * (iq ** 2) * lin_diag
-        q, r, g, err, used = sk.lr_mirror_step(
-            state.q, state.r, state.g, gq, gr, gg, mu, nu, eps,
-            lr_gamma, cfg.sinkhorn_iters, cfg.sinkhorn_chunk,
-            inner_tol, cfg.g_floor, cfg.lowrank_backend,
-            cost_dtype=cfg.cost_dtype)
+        with jax.named_scope(scopes.GRAD):
+            gq, gr, gg = op.grads(state, dx2, dy2, cfg.g_floor)
+            iq = 1.0 / jnp.maximum(state.g, cfg.g_floor)
+            fr = fsq @ state.r       # (M, r)
+            fq = fsq.T @ state.q     # (N, r)
+            lin_diag = jnp.sum(state.q * fr, axis=0)    # diag(Qᵀ C² R)
+            gq = theta * gq + (1.0 - theta) * fr * iq[None, :]
+            gr = theta * gr + (1.0 - theta) * fq * iq[None, :]
+            gg = theta * gg - (1.0 - theta) * (iq ** 2) * lin_diag
+        with jax.named_scope(scopes.SINKHORN):
+            q, r, g, err, used = sk.lr_mirror_step(
+                state.q, state.r, state.g, gq, gr, gg, mu, nu, eps,
+                lr_gamma, cfg.sinkhorn_iters, cfg.sinkhorn_chunk,
+                inner_tol, cfg.g_floor, cfg.lowrank_backend,
+                cost_dtype=cfg.cost_dtype)
         return type(state)(q, r, g), err, used
 
     return step

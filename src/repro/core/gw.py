@@ -60,6 +60,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import sinkhorn as sk
 from repro.core.coupling import (Coupling, FullCoupling, LowRankCoupling,
                                  coupling_delta, full_init, lowrank_init)
@@ -222,6 +223,7 @@ def _result_of(coupling: Coupling, value, marginal_err, errs,
                     errs=errs, info=info, coupling=coupling)
 
 
+@jax.named_scope(scopes.VALUE)
 def gw_energy(grid_x, grid_y, gamma, backend: str = "cumsum",
               dx2_mu=None, dy2_nu=None):
     """E(Γ) = Σ (d^X_ij − d^Y_pq)² γ_ip γ_jq, via the three-term expansion."""
@@ -236,11 +238,13 @@ def gw_step_fn(op: GradientOperator, c1, mu, nu, cfg: GWConfig):
     State: a `FullCoupling`."""
 
     def step(state, eps, inner_tol):
-        gamma, f, g, err, used = sk.solve_adaptive(
-            op.grad(state.plan, c1), mu, nu, eps, cfg.sinkhorn_iters,
-            cfg.sinkhorn_chunk, inner_tol, cfg.sinkhorn_mode, state.f,
-            state.g, backend=cfg.sinkhorn_backend,
-            cost_dtype=cfg.cost_dtype)
+        with jax.named_scope(scopes.GRAD):
+            cost = op.grad(state.plan, c1)
+        with jax.named_scope(scopes.SINKHORN):
+            gamma, f, g, err, used = sk.solve_adaptive(
+                cost, mu, nu, eps, cfg.sinkhorn_iters, cfg.sinkhorn_chunk,
+                inner_tol, cfg.sinkhorn_mode, state.f, state.g,
+                backend=cfg.sinkhorn_backend, cost_dtype=cfg.cost_dtype)
         return FullCoupling(gamma, f, g), err, used
 
     return step
@@ -259,11 +263,13 @@ def gw_lr_step_fn(op: LowRankGradientOperator, dx2, dy2, mu, nu,
     ε-schedules work identically across representations."""
 
     def step(state, eps, inner_tol):
-        gq, gr, gg = op.grads(state, dx2, dy2, cfg.g_floor)
-        q, r, g, err, used = sk.lr_mirror_step(
-            state.q, state.r, state.g, gq, gr, gg, mu, nu, eps, lr_gamma,
-            cfg.sinkhorn_iters, cfg.sinkhorn_chunk, inner_tol, cfg.g_floor,
-            cfg.lowrank_backend, cost_dtype=cfg.cost_dtype)
+        with jax.named_scope(scopes.GRAD):
+            gq, gr, gg = op.grads(state, dx2, dy2, cfg.g_floor)
+        with jax.named_scope(scopes.SINKHORN):
+            q, r, g, err, used = sk.lr_mirror_step(
+                state.q, state.r, state.g, gq, gr, gg, mu, nu, eps,
+                lr_gamma, cfg.sinkhorn_iters, cfg.sinkhorn_chunk, inner_tol,
+                cfg.g_floor, cfg.lowrank_backend, cost_dtype=cfg.cost_dtype)
         return LowRankCoupling(q, r, g), err, used
 
     return step
@@ -279,6 +285,7 @@ def _static_rank(cfg: GWConfig) -> int:
     return cfg.plan_rank
 
 
+@jax.named_scope(scopes.INIT)
 def gw_init_state(mu, nu, gamma0=None, cfg: GWConfig | None = None,
                   geom_x=None, geom_y=None):
     """The standard cold start as a `Coupling`: product-coupling plan with
@@ -302,7 +309,7 @@ def gw_plan_solve(op: GradientOperator, c1, mu, nu, cfg: GWConfig,
     ``(FullCoupling, ConvergenceInfo)``."""
     ctl = resolve_controls(cfg, controls)
     if state0 is None:
-        state0 = full_init(mu, nu)
+        state0 = gw_init_state(mu, nu)
     step = gw_step_fn(op, c1, mu, nu, cfg)
     return mirror_descent(step, state0, coupling_delta, ctl,
                           cfg.outer_iters)
@@ -328,7 +335,8 @@ def _implicit_solve(cfg: GWConfig, inputs, controls):
     if cfg.plan == "lowrank":
         op = LowRankGradientOperator(gx, gy, cfg.backend, cfg.cost_rank,
                                      cfg.lowrank_backend)
-        dx2, dy2 = op.constant_term(mu, nu)
+        with jax.named_scope(scopes.INIT):
+            dx2, dy2 = op.constant_term(mu, nu)
         if feat is None:
             step = gw_lr_step_fn(op, dx2, dy2, mu, nu, cfg,
                                  controls.lr_gamma)
@@ -337,20 +345,21 @@ def _implicit_solve(cfg: GWConfig, inputs, controls):
             step = _fgw.fgw_lr_step_fn(op, dx2, dy2, feat ** 2, cfg.theta,
                                        mu, nu, cfg, controls.lr_gamma)
         if state0 is None:
-            state0 = lowrank_init(mu, nu, _static_rank(cfg),
-                                  method=cfg.lowrank_init, geom_x=op.geom_x,
-                                  geom_y=op.geom_y)
+            state0 = gw_init_state(mu, nu, cfg=cfg, geom_x=op.geom_x,
+                                   geom_y=op.geom_y)
         return mirror_descent(step, state0, coupling_delta, controls,
                               cfg.outer_iters)
     op = GradientOperator(gx, gy, cfg.backend)
-    c1, _, _ = op.constant_term(mu, nu)
+    with jax.named_scope(scopes.INIT):
+        c1, _, _ = op.constant_term(mu, nu)
     if state0 is None:
-        state0 = full_init(mu, nu)
+        state0 = gw_init_state(mu, nu)
     if feat is None:
         step = gw_step_fn(op, c1, mu, nu, cfg)
     else:
         from repro.core import fgw as _fgw
-        c2 = (1.0 - cfg.theta) * feat ** 2 + cfg.theta * c1
+        with jax.named_scope(scopes.INIT):
+            c2 = (1.0 - cfg.theta) * feat ** 2 + cfg.theta * c1
         step = _fgw.fgw_step_fn(op, c2, cfg.theta, mu, nu, cfg)
     return mirror_descent(step, state0, coupling_delta, controls,
                           cfg.outer_iters)
@@ -415,6 +424,7 @@ def _implicit_step(cfg: GWConfig, state, inputs, controls):
     return FullCoupling(plan, f, g)
 
 
+@jax.named_scope(scopes.VALUE)
 def _implicit_value(cfg: GWConfig, state, inputs, controls):
     """`ImplicitSpec.value` — the PRIMAL objective, bit-compatible with the
     historical forward expressions (precomputed (D∘D)-applies at (μ, ν) for
@@ -437,6 +447,7 @@ def _implicit_value(cfg: GWConfig, state, inputs, controls):
     return _fgw.fgw_full_value(op, feat, state.plan, cfg.theta)
 
 
+@jax.named_scope(scopes.VALUE)
 def _implicit_value_bwd(cfg: GWConfig, state, inputs, controls):
     """`ImplicitSpec.value_bwd` — the gradient-correct objective for the
     backward pass: the plan's OWN marginals everywhere (E(Γ) depends on μ/ν
@@ -557,9 +568,10 @@ def lowrank_descent(step, mu, nu, cfg: GWConfig, ctl: SolveControls,
     seeding) works in either mode.
     """
     if not isinstance(cfg.plan_rank, str):
-        state0 = lowrank_init(mu, nu, cfg.plan_rank,
-                              method=cfg.lowrank_init, geom_x=geom_x,
-                              geom_y=geom_y)
+        with jax.named_scope(scopes.INIT):
+            state0 = lowrank_init(mu, nu, cfg.plan_rank,
+                                  method=cfg.lowrank_init, geom_x=geom_x,
+                                  geom_y=geom_y)
         return mirror_descent(step, state0, coupling_delta, ctl,
                               cfg.outer_iters)
     if isinstance(mu, jax.core.Tracer):
@@ -595,14 +607,16 @@ def _entropic_gw_lowrank(grid_x, grid_y, mu, nu, cfg: GWConfig,
     `fixed_point_value` in `entropic_gw` instead."""
     op = LowRankGradientOperator(grid_x, grid_y, cfg.backend, cfg.cost_rank,
                                  cfg.lowrank_backend)
-    dx2, dy2 = op.constant_term(mu, nu)
+    with jax.named_scope(scopes.INIT):
+        dx2, dy2 = op.constant_term(mu, nu)
     step = gw_lr_step_fn(op, dx2, dy2, mu, nu, cfg, ctl.lr_gamma)
     # init sees the CONVERTED geometries (op's factored pair) so one-shot,
     # batched, and padded-lane solves derive k-means seeds from identical
     # embeddings
     coup, info = lowrank_descent(step, mu, nu, cfg, ctl, op.geom_x,
                                  op.geom_y)
-    value = op.energy(coup, cfg.g_floor)
+    with jax.named_scope(scopes.VALUE):
+        value = op.energy(coup, cfg.g_floor)
     return _result_of(coup, value, info.marginal_err, info.err_trace, info)
 
 
@@ -682,7 +696,8 @@ def _segment_stacked_impl(geoms_x, geoms_y, mus, nus, feats,
         if cfg.plan == "lowrank":
             op = LowRankGradientOperator(gx, gy, cfg.backend, cfg.cost_rank,
                                          cfg.lowrank_backend)
-            dx2, dy2 = op.constant_term(mu, nu)
+            with jax.named_scope(scopes.INIT):
+                dx2, dy2 = op.constant_term(mu, nu)
             if feat is None:
                 step = gw_lr_step_fn(op, dx2, dy2, mu, nu, cfg,
                                      ctl.lr_gamma)
@@ -693,22 +708,27 @@ def _segment_stacked_impl(geoms_x, geoms_y, mus, nus, feats,
                                            ctl.lr_gamma)
             c = mirror_descent_segment(step, coupling_delta, ctl,
                                        cfg.outer_iters, c, segment)
-            if feat is None:
-                return c, op.energy(c.state, cfg.g_floor)
-            from repro.core import fgw as _fgw
-            return c, _fgw.fgw_lr_value(op, feat ** 2, c.state, cfg.theta,
-                                        cfg.g_floor)
+            with jax.named_scope(scopes.VALUE):
+                if feat is None:
+                    return c, op.energy(c.state, cfg.g_floor)
+                from repro.core import fgw as _fgw
+                return c, _fgw.fgw_lr_value(op, feat ** 2, c.state,
+                                            cfg.theta, cfg.g_floor)
         op = GradientOperator(gx, gy, cfg.backend)
-        c1, dx2_mu, dy2_nu = op.constant_term(mu, nu)
+        with jax.named_scope(scopes.INIT):
+            c1, dx2_mu, dy2_nu = op.constant_term(mu, nu)
         if feat is None:
             c = gw_plan_segment(op, c1, mu, nu, cfg, ctl, c, segment)
-            return c, op.energy(c.state.plan, dx2_mu, dy2_nu)
+            with jax.named_scope(scopes.VALUE):
+                return c, op.energy(c.state.plan, dx2_mu, dy2_nu)
         from repro.core import fgw as _fgw
-        c2 = (1.0 - cfg.theta) * feat ** 2 + cfg.theta * c1
+        with jax.named_scope(scopes.INIT):
+            c2 = (1.0 - cfg.theta) * feat ** 2 + cfg.theta * c1
         step = _fgw.fgw_step_fn(op, c2, cfg.theta, mu, nu, cfg)
         c = mirror_descent_segment(step, coupling_delta, ctl,
                                    cfg.outer_iters, c, segment)
-        return c, _fgw.fgw_full_value(op, feat, c.state.plan, cfg.theta)
+        with jax.named_scope(scopes.VALUE):
+            return c, _fgw.fgw_full_value(op, feat, c.state.plan, cfg.theta)
 
     return jax.vmap(one)(geoms_x, geoms_y, mus, nus, feats, controls,
                          carry)

@@ -74,6 +74,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
@@ -211,6 +213,7 @@ class MirrorCarry:
         return cls(*children)
 
 
+@jax.named_scope(scopes.INIT)
 def init_carry(state0, outer_cap: int) -> MirrorCarry:
     """A fresh carry: no steps taken, trace all-NaN, not converged."""
     ft = jnp.result_type(float)
@@ -245,6 +248,7 @@ def plan_delta(new_state, old_state):
     return jnp.abs(new_state[0] - old_state[0]).sum()
 
 
+@jax.named_scope(scopes.DRIVER)
 def mirror_descent_segment(step_fn, delta_fn, controls: SolveControls,
                            outer_cap: int, carry: MirrorCarry,
                            segment: int | None = None) -> MirrorCarry:
@@ -282,6 +286,9 @@ def mirror_descent_segment(step_fn, delta_fn, controls: SolveControls,
     equals ``t`` and the iterates are bit-identical to the un-clocked
     driver; dwell is also disabled under ``tol=0`` (fixed mode) and
     bounded overall by ``outer_cap // 2`` extra steps.
+
+    The loop runs under the ``gw.driver`` scope and the plan change under
+    ``gw.delta``; ``step_fn`` names its own stages (`repro.scopes`).
     """
     t_end = (jnp.asarray(outer_cap, jnp.int32) if segment is None
              else jnp.minimum(jnp.asarray(outer_cap, jnp.int32),
@@ -304,9 +311,10 @@ def mirror_descent_segment(step_fn, delta_fn, controls: SolveControls,
         new_state, step_err, used = step_fn(c.state,
                                             controls.eps_at(c.stage),
                                             inner_tol)
+        with jax.named_scope(scopes.DELTA):
+            delta = delta_fn(new_state, c.state)
         conv = ((controls.tol > 0.0) & controls.anneal_done(c.stage)
-                & (delta_fn(new_state, c.state) <= controls.tol)
-                & (step_err <= controls.tol))
+                & (delta <= controls.tol) & (step_err <= controls.tol))
         # hold the annealing stage while the inner solver is capped out
         # mid-ramp; (t - stage) counts holds already spent, bounding dwell.
         hold = ((controls.tol > 0.0)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 LANES = 128
 BLOCK_ROWS = 128
 
@@ -142,6 +144,7 @@ def fgc_apply_dtilde_pallas(x, p: int = 1, block_rows: int = BLOCK_ROWS,
         scratch_shapes=[pltpu.VMEM((p + 1, LANES), dtype),
                         pltpu.VMEM((p + 1, LANES), dtype)],
         interpret=interpret,
+        name=scopes.FGC_DTILDE_KERNEL,
     )(xp, xp, *consts)
     return (y_lo + y_hi)[:n, :b]
 
@@ -175,5 +178,6 @@ def fgc_apply_l_pallas(x, p: int = 1, block_rows: int = BLOCK_ROWS,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda c, r: (r, c)),
         scratch_shapes=[pltpu.VMEM((p + 1, LANES), dtype)],
         interpret=interpret,
+        name=scopes.FGC_L_KERNEL,
     )(xp, l_r, v, p_r, t)
     return y[:n, :b]

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
 from repro.kernels.sinkhorn_step import (BM, _cast_cost, _col, _finish_lse,
                                          _online_lse_update, _row,
                                          default_interpret)
@@ -148,6 +149,7 @@ def lr_dykstra_half_pallas(lk, gcol, logw, interpret: bool | None = None,
         scratch_shapes=[pltpu.VMEM((1, rp), dtype),
                         pltpu.VMEM((1, rp), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
+        name=scopes.LR_DYKSTRA_HALF_KERNEL,
     )(lkp, gp, logwp)
     return f[:n, 0], col[0, :r]
 
@@ -257,6 +259,7 @@ def lr_gram_chain_pallas(a_fac, b_fac, q, w, interpret: bool | None = None):
                         pltpu.VMEM((1, rp), dtype),
                         pltpu.VMEM((1, rp), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
+        name=scopes.LR_GRAM_CHAIN_KERNEL,
     )(ap, bp, qp, wp)
     return bq[:c, :r], gram[:r, :r], sq[0, :r], tq[0, :r]
 
@@ -319,6 +322,7 @@ def lr_grad_combine_pallas(a_fac, w_small, d2, s_other, t_other, iq,
         ],
         out_specs=pl.BlockSpec((BM, rp), lambda i: (i, 0)),
         interpret=default_interpret() if interpret is None else interpret,
+        name=scopes.LR_GRAD_COMBINE_KERNEL,
     )(ap, d2p, wp, sp, tp, iqp)
     return out[:n, :r]
 

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 BM = 128
 BN = 128
 
@@ -198,6 +200,7 @@ def sinkhorn_row_update_pallas(cost, g, log_mu, eps,
         scratch_shapes=[pltpu.VMEM((BM, 1), dtype),
                         pltpu.VMEM((BM, 1), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
+        name=scopes.SINKHORN_ROW_KERNEL,
     )(eps_arr, costp, gp, logmup)
     return f[:m, 0]
 
@@ -231,6 +234,7 @@ def sinkhorn_col_update_pallas(cost, f, log_nu, eps,
         scratch_shapes=[pltpu.VMEM((1, BN), dtype),
                         pltpu.VMEM((1, BN), dtype)],
         interpret=default_interpret() if interpret is None else interpret,
+        name=scopes.SINKHORN_COL_KERNEL,
     )(eps_arr, costp, fp, lognup)
     return g[0, :n]
 
