@@ -18,6 +18,10 @@ Backends
             (i-j)^p = Σ_s C(p,s) i^{p-s} (-j)^s  turns Lx into p+1 exclusive
             cumulative sums — log-depth parallel prefix, no sequential loop.
             Indices are centered (i → i−N/2) to halve monomial magnitudes.
+            A D̃-apply along an axis that fits one MXU tile (N ≤ 128), to
+            more than one column, is instead one float32 matmul by the
+            constant D̃ at HIGHEST precision: the paper's recursion blocked
+            at one block, so no moment carry and no prefix sum.
 ``dense``   explicit Toeplitz matmul (oracle; MXU path for small N).
 ``pallas``  Pallas TPU kernel (see repro.kernels.fgc_scan), validated in
             interpret mode on CPU.
@@ -36,7 +40,7 @@ single-sweep implementation:
 * ``cumsum``  the p+1 moment cumsums Σ_j t_j^s x_j are computed ONCE and
               reused for both triangles (prefix reads for L, suffix =
               total − prefix for Lᵀ) — half the cumsum traffic of the
-              two-pass form.
+              two-pass form; an axis of N ≤ 128 is one (N, N) matmul.
 * ``pallas``  fused TPU kernel (`fgc_scan.fgc_apply_dtilde_pallas`): one
               sequential row-block sweep computes block r of Lx and block
               nrb−1−r of Lᵀx per step, sharing the x block loads' DMA slots.
@@ -76,6 +80,12 @@ def lower_toeplitz(n: int, p: int, dtype=None):
     idx = jnp.arange(n, dtype=dtype)
     diff = idx[:, None] - idx[None, :]
     return jnp.where(diff > 0, diff ** p, jnp.zeros((), dtype))
+
+
+def _dtilde_matrix(n: int, p: int, dtype):
+    """Dense D̃ = L + Lᵀ, D̃[i,j] = |i-j|^p (p ≥ 1)."""
+    lo = lower_toeplitz(n, p, dtype)
+    return lo + lo.T
 
 
 # ---------------------------------------------------------------------------
@@ -213,7 +223,29 @@ def _apply_D_scan(x2, p: int):
     return ys[:, :b] + jnp.flip(ys[:, b:], axis=0)
 
 
+# The MXU tile width of the TPU: an axis this short is one (N, N) tile.
+TILE = 128
+
+
 def _apply_D_cumsum(x2, p: int):
+    """D̃x: one matmul by the constant D̃ on an axis of at most TILE points,
+    the shared-moment cumsums on a longer one or on a single vector.
+
+    HIGHEST keeps the float32 product float32 on the TPU, whose default f32
+    dot is one bfloat16 pass.  A single vector costs little either way, and
+    its matrix-vector product rounds differently from the matrix product
+    that `jax.vmap` makes of it, so lanes of a batched solve would depend on
+    the batch width.
+    """
+    n, b = x2.shape
+    if n > TILE or b == 1:
+        return _apply_D_moments(x2, p)
+    with jax.ensure_compile_time_eval():
+        d = _dtilde_matrix(n, p, x2.dtype)
+    return jnp.dot(d, x2, precision=jax.lax.Precision.HIGHEST)
+
+
+def _apply_D_moments(x2, p: int):
     """Shared-moment closed form: each cumsum Σ_j t_j^s x_j serves BOTH
     triangles — prefix (exclusive) for L, suffix = total − inclusive for Lᵀ —
     so D̃x costs p+1 cumsums instead of 2(p+1).
@@ -239,8 +271,7 @@ def _apply_D_cumsum(x2, p: int):
 
 
 def _apply_D_dense(x2, p: int):
-    lo = lower_toeplitz(x2.shape[0], p, x2.dtype)
-    return (lo + lo.T) @ x2
+    return _dtilde_matrix(x2.shape[0], p, x2.dtype) @ x2
 
 
 def _apply_D_pallas(x2, p: int):

@@ -111,6 +111,59 @@ def test_fused_dtilde_f32(p, backend):
                                atol=1e-5 * np.abs(want).max())
 
 
+def _rel_err(got, want):
+    return float(np.abs(np.asarray(got, np.float64) - want).max()
+                 / np.abs(want).max())
+
+
+@pytest.mark.parametrize("layout", ["1d", "2d", "3d"])
+@pytest.mark.parametrize("p", [1, 2, 3, 4])
+@pytest.mark.parametrize("n", [2, 33, 127, 128])
+def test_cumsum_tile_dtilde_f32(n, p, layout):
+    """An axis of at most one MXU tile takes the float32 matmul, a single
+    vector the moment sums: both within 1e-5 of the float64 oracle, which
+    one bfloat16 pass (the TPU's default f32 dot) misses."""
+    shape, axis = {"1d": ((n,), 0), "2d": ((n, 3), 0),
+                   "3d": ((3, n, 5), 1)}[layout]
+    x = jnp.asarray(RNG.normal(size=shape), dtype=jnp.float32)
+    lo = np.asarray(fgc.lower_toeplitz(n, p, jnp.float64))
+    d = lo + lo.T
+    want = np.moveaxis(np.tensordot(d, np.asarray(x, np.float64),
+                                    axes=([1], [axis])), 0, axis)
+    apply = lambda v: fgc.apply_abs_power(v, axis, p, "cumsum")  # noqa: E731
+    got = apply(x)
+    assert got.dtype == jnp.float32
+    assert _rel_err(got, want) < 1e-5
+    jaxpr = str(jax.make_jaxpr(apply)(x))
+    assert ("dot_general" in jaxpr) == (layout != "1d")
+    assert ("cumsum" in jaxpr) == (layout == "1d")
+    if layout == "3d":    # the served form: vmapped lanes under jit
+        lanes = jax.jit(jax.vmap(
+            lambda v: fgc.apply_abs_power(v, 0, p, "cumsum")))(x)
+        assert _rel_err(lanes, want) < 1e-5
+    one_pass = jnp.moveaxis(jnp.tensordot(
+        jnp.asarray(d, jnp.bfloat16), x.astype(jnp.bfloat16),
+        axes=([1], [axis]), preferred_element_type=jnp.float32), 0, axis)
+    assert _rel_err(one_pass, want) > 1e-5
+
+
+@pytest.mark.parametrize("layout", ["2d", "3d"])
+@pytest.mark.parametrize("p", [1, 2, 3, 4])
+@pytest.mark.parametrize("n", [129, 200])
+def test_cumsum_long_axis_keeps_moment_sums(n, p, layout):
+    """Past one tile the cumsum backend is the p+1 shared-moment cumsums,
+    bit for bit."""
+    shape, axis = {"2d": ((n, 3), 0), "3d": ((3, n, 5), 1)}[layout]
+    x = jnp.asarray(RNG.normal(size=shape), dtype=jnp.float32)
+    x2, front, ax = fgc._to_front(x, axis)
+    want = fgc._from_front(fgc._apply_D_moments(x2, p), front, ax)
+    got = fgc.apply_abs_power(x, axis, p, "cumsum")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    jaxpr = jax.make_jaxpr(lambda v: fgc.apply_abs_power(v, axis, p,
+                                                         "cumsum"))(x)
+    assert "cumsum" in str(jaxpr) and "dot_general" not in str(jaxpr)
+
+
 def test_fused_scan_is_single_sweep():
     """The fused scan backend must lower to exactly ONE lax.scan (the
     bidirectional sweep), not the historical L-pass + flip/L/flip pass."""

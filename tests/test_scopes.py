@@ -66,15 +66,20 @@ def test_compiled_solve_carries_every_stage_scope(build, stages):
     assert found == set(stages)
 
 
-def test_fgc_sums_keep_their_scope():
-    """The FGC cumulative sums lower through a function call of their own;
-    the compiled reduce-windows still carry the caller's scope."""
+def test_fgc_matmuls_keep_their_scope():
+    """The grid's axes fit one tile, so each FGC D̃-apply is a matmul by a
+    constant (n, n) tile; every such dot carries its stage's scope."""
     hlo = _one_shot().compile().as_text()
-    windows = [ln for ln in hlo.splitlines() if " reduce-window(" in ln
-               and "op_name=" in ln]
-    assert windows
+    n = GRID.n
+    tiles = set(re.findall(
+        rf"(%constant[.\d]*) = f\d+\[{n},{n}\]\S* constant\(", hlo))
+    matmuls = [ln for ln in hlo.splitlines()
+               if re.search(r"= \S+ (dot|convolution)\(", ln)
+               and any(re.search(re.escape(c) + r"[,)]", ln) for c in tiles)]
+    assert tiles and matmuls
     assert all(re.search(r'op_name="[^"]*gw\.(grad|value|init)', ln)
-               for ln in windows)
+               for ln in matmuls)
+    assert any('gw.grad' in ln for ln in matmuls)
 
 
 def _pallas_names(fn) -> list:

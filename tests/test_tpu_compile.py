@@ -16,6 +16,7 @@ this file loads the TPU compiler.  Nothing is compiled for a real device.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +66,13 @@ def _compile(fn, *args):
 
 def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _assert_fits_v5e(compiled):
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, mem
 
 
 @pytest.mark.parametrize("cost_dtype", ["f32", "bf16"])
@@ -134,6 +142,26 @@ def test_fgc_kernel_compiles(chip, apply, p):
                             _spec(chip, (N_DENSE, 128))))
 
 
+def test_fgc_product_is_highest_matmuls(chip):
+    """D_X Γ D_Y on the 128×128 grid, the FGC `cumsum` backend: each axis
+    fits one MXU tile, so the D̃-applies are float32 matmuls at HIGHEST
+    precision and no prefix sum (`reduce-window`) is left."""
+    from repro.core.gradient import GradientOperator
+    from repro.core.grids import Grid2D
+
+    grid = Grid2D(128, 1.0 / 127, 1)
+    op = GradientOperator(grid, grid, "cumsum")
+    compiled = _compile(op.product, _spec(chip, (N_DENSE, N_DENSE)))
+    hlo = compiled.as_text()
+    assert "reduce-window(" not in hlo
+    matmuls = [ln for ln in hlo.splitlines()
+               if re.search(r"= \S+ (dot|convolution)\(", ln)]
+    assert matmuls
+    assert all("operand_precision={highest,highest}" in ln
+               for ln in matmuls)
+    _assert_fits_v5e(compiled)
+
+
 def test_dense_segment_step_fits_v5e(chip, monkeypatch):
     """One outer step of the dense solve at the smoke's size, with the
     backends "auto" picks on a TPU: Sinkhorn sweeps through the compiled
@@ -170,7 +198,4 @@ def test_dense_segment_step_fits_v5e(chip, monkeypatch):
         lambda a: _spec(chip, a.shape, a.dtype), (ctl, carry))
     compiled = _compile(step, vec, vec, ctl, carry)
     _assert_kernel(compiled)
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert total < V5E_HBM_BYTES, mem
+    _assert_fits_v5e(compiled)
