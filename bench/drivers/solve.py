@@ -5,12 +5,13 @@ once from the mix's ``pool_seed`` at the configuration's shapes, taken in
 an order drawn from the seed, a new order for each pass over the pool.
 Each solve goes through one jitted ``entropic_gw`` compiled during set-up
 and ends in ``block_until_ready``.  The window runs from the start of the
-first solve to the end of the last one it started; ``solve_s`` is the
-window over the number of solves.  A solve that returns a value that is
-not finite, or that stops at its outer cap short of the configuration's
-tolerance, has failed.  One solve, drawn from the seed by reservoir
-sampling over the window, is kept and checked against the reference
-afterwards.
+first solve to the end of the first pass over the pool that ends after
+``seconds``, so every seed solves each pool problem equally often;
+``solve_s`` is the window over the number of solves.  A solve that
+returns a value that is not finite, or that stops at its outer cap short
+of the configuration's tolerance, has failed.  One solve, drawn from the
+seed by reservoir sampling over the window, is kept and checked against
+the reference afterwards.
 """
 from __future__ import annotations
 
@@ -99,7 +100,7 @@ class SolveDriver:
             if self.pick.random() < 1.0 / i:
                 self.kept = (i - 1, j, res)
             del res
-            if time.perf_counter() - t0 >= seconds:
+            if i % self.pool == 0 and time.perf_counter() - t0 >= seconds:
                 break
         window_s = time.perf_counter() - t0
         return Window(attempted=i, failed=failed, window_s=window_s,

@@ -11,10 +11,10 @@ says which of the two shapes of work it asks for:
            once.  Without ``pool`` every request is a new problem, and
            every wave holds the configuration's ``sizes`` in equal shares
            (the first sizes one more where they do not divide), in an order
-           drawn from the seed.  With ``problem_seed`` set, the problems
-           are drawn from it and not from the run's seed, and wave k holds
-           the same problems and sizes for every seed, in the seed's
-           order: every seed sends the same work.  With
+           drawn from the seed.  With ``problem_seed`` set, every draw of
+           the stream (problems, sizes, order) comes from it and not from
+           the run's seed, so every seed sends the same waves: the engine's
+           slot batches follow the order, and with them the work.  With
            ``pool`` set, the pool's problems take the sizes in equal shares,
            and each request is one of them under Zipf popularity ``zipf_s``
            (which problem is most popular is drawn from the seed); a
@@ -74,12 +74,12 @@ class Waves:
         self.wave_size = int(traffic["wave_size"])
         self.sizes = [int(s) for s in sizes]
         self.dim = dim
+        if traffic.get("problem_seed") is not None:
+            seed = int(traffic["problem_seed"])
         self.r = rng(seed, stream)
         self.pool = traffic.get("pool")
         self.identical_share = float(traffic.get("identical_share", 1.0))
         self.next_problem = 0
-        self.fixed = (None if traffic.get("problem_seed") is None
-                      else rng(int(traffic["problem_seed"]), stream))
         if self.pool:
             self.pool_sizes = self.r.permutation(self.shares(self.pool))
             ranks = np.arange(1, self.pool + 1, dtype=np.float64)
@@ -106,10 +106,6 @@ class Waves:
             return [self.request() for _ in range(self.wave_size)]
         first = self.next_problem
         self.next_problem += self.wave_size
-        if self.fixed is not None:
-            sizes = self.fixed.permutation(self.shares(self.wave_size))
-            return [Request(first + int(i), int(sizes[i]), "first")
-                    for i in self.r.permutation(self.wave_size)]
         sizes = self.r.permutation(self.shares(self.wave_size))
         return [Request(first + i, int(n), "first")
                 for i, n in enumerate(sizes)]

@@ -1,10 +1,15 @@
 """The control and the planted faults of each cell, driven through a whole
 run on the CPU at the tiny sizes of ``tiny_root``: each reads not correct."""
+import dataclasses
+import json
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from bench.spec import merged
 from bench.tests._whole_run import run
+from bench.tests.test_bench_window import FULL_WIDTHS
 
 @pytest.mark.parametrize("cell", ["grid2d-128.solve",
                                   "cloud3d-served.unique"])
@@ -75,3 +80,25 @@ def test_planted_fault_reads_not_correct(tiny_root, monkeypatch, cell,
     finally:
         jax.clear_caches()
     assert not r["correct"], log
+
+
+def test_one_lane_altered_reads_not_correct(tiny_root, monkeypatch):
+    """Only the answers harvested from a lane past the first of a slot
+    batch are wrong, at the configuration's own ``max_batch`` and one tiny
+    size, so that each wave's run starts wide: the check sees every lane
+    position, not a sample of sizes."""
+    from repro.serve import engine
+
+    p = tiny_root / "bench" / "configs" / "cloud3d-served.json"
+    p.write_text(json.dumps(merged(json.loads(p.read_text()), FULL_WIDTHS)))
+    real = engine.GWEngine._harvest
+
+    def _harvest(self, carry, values, i, req):
+        res = real(self, carry, values, i, req)
+        return (dataclasses.replace(res, value=res.value * 1.1) if i > 0
+                else res)
+
+    monkeypatch.setattr(engine.GWEngine, "_harvest", _harvest)
+    r, log = run(tiny_root, "cloud3d-served.unique")
+    assert r["failed"] == 0, log
+    assert r["checks"]["solve_gap"]["value"] > 0.05, log

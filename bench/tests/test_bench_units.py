@@ -105,9 +105,10 @@ def test_every_metric_of_benchmark_json_has_a_reader_and_a_cell():
         assert "setup_s" in e2e and len(e2e) >= 2 and c.per_layer
 
 
-def _waves(seed, mix="zipf", stream=generate.WINDOW, n=4):
+def _waves(seed, mix="zipf", stream=generate.WINDOW, n=4, **cut):
     traffic = json.loads((ROOT / "bench" / "traffic" / f"{mix}.json")
                          .read_text())
+    traffic.update(cut)
     w = generate.Waves(traffic, [1024, 2048, 4096], 3, seed, stream)
     return [[(r.problem, r.size, r.variant,
               None if r.rotation is None else r.rotation.tobytes())
@@ -116,10 +117,16 @@ def _waves(seed, mix="zipf", stream=generate.WINDOW, n=4):
 
 @pytest.mark.parametrize("mix", ["unique", "zipf"])
 def test_mix_gives_the_same_work_for_a_seed_and_other_work_for_another(mix):
+    """Without ``problem_seed`` (the unique mix's is taken out here) the
+    run's seed draws the waves."""
     big = 2 ** 33 + 1
-    assert _waves(big, mix) == _waves(big, mix)
-    assert _waves(big, mix) != _waves(big + 1, mix)
-    assert _waves(big, mix) != _waves(big, mix, generate.WARMUP)
+
+    def waves(seed, stream=generate.WINDOW):
+        return _waves(seed, mix, stream, problem_seed=None)
+
+    assert waves(big) == waves(big)
+    assert waves(big) != waves(big + 1)
+    assert waves(big) != waves(big, generate.WARMUP)
 
 
 def test_zipf_mix_repeats_some_problems_both_ways():
@@ -137,20 +144,23 @@ def test_unique_mix_never_repeats_a_problem():
     assert {s for _, s, *_ in reqs} == {1024, 2048, 4096}
 
 
-def test_unique_mix_sends_every_seed_the_same_sizes_in_another_order():
-    a, b = _waves(11, "unique"), _waves(12, "unique")
-    for wa, wb in zip(a, b):
-        sizes = sorted(s for _, s, *_ in wa)
-        assert sizes == sorted(s for _, s, *_ in wb)
+def test_unique_mix_sends_every_seed_the_same_waves():
+    a = _waves(11, "unique")
+    assert a == _waves(2 ** 33 + 12, "unique")
+    for wave in a:
+        sizes = sorted(s for _, s, *_ in wave)
         assert sizes == sorted([1024] * 11 + [2048] * 11 + [4096] * 10)
-    assert [s for _, s, *_ in a[0]] != [s for _, s, *_ in b[0]]
+    assert len({tuple(s for _, s, *_ in w) for w in a}) == len(a)
 
 
-def test_unique_mix_sends_every_seed_the_same_problems_in_another_order():
-    a, b = _waves(11, "unique"), _waves(12, "unique")
-    for wa, wb in zip(a, b):
-        assert sorted(wa, key=str) == sorted(wb, key=str)
-    assert a != b
+def test_problem_seed_draws_the_whole_stream():
+    """Problems, sizes and order all follow ``problem_seed``; the warm-up
+    stream stays apart."""
+    a = _waves(11, "unique", problem_seed=5)
+    assert a == _waves(12, "unique", problem_seed=5)
+    assert a != _waves(11, "unique", problem_seed=6)
+    assert a == _waves(5, "unique", problem_seed=None)
+    assert a != _waves(11, "unique", generate.WARMUP, problem_seed=5)
 
 
 def test_rotations_are_rotations():
