@@ -1,15 +1,19 @@
-"""The two shapes of work a traffic mix can ask for, by its ``kind``.
+"""The shapes of work a traffic mix can ask for, one file per ``kind``.
 
-A driver is built with ``(config, traffic, seed)`` and does all of its
-set-up there: data, compilation and warm-up.  ``window(seconds)`` measures;
-``release()`` drops the program's state; ``check()`` compares what the
-window produced with the reference and returns ``(checks, info lines)``.
+``bench/drivers/<kind>.py`` defines ``Driver``.  A driver is built with
+``(config, traffic, seed)`` and does all of its set-up there: data,
+compilation and warm-up.  ``window(seconds)`` measures; ``release()``
+drops the program's state; ``check()`` compares what the window produced
+with the reference and returns ``(checks, info lines)``.
 """
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 
 import jax
+
+from bench import spec
 
 
 @dataclasses.dataclass
@@ -39,11 +43,7 @@ def span(name: str):
     return jax.profiler.TraceAnnotation(f"bench.{name}")
 
 
-def driver(kind: str):
-    if kind == "solve":
-        from bench.drivers.solve import SolveDriver
-        return SolveDriver
-    if kind == "waves":
-        from bench.drivers.waves import WavesDriver
-        return WavesDriver
-    raise ValueError(f"unknown traffic kind {kind!r}")
+def driver(kind: str, root: Path):
+    """The ``Driver`` class of ``<root>/bench/drivers/<kind>.py``."""
+    return spec.load_file(root / "bench" / "drivers" / f"{kind}.py",
+                          f"driver for traffic kind {kind!r}").Driver

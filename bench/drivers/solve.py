@@ -3,27 +3,32 @@
 Every seed solves the same work: a pool of ``pool`` measure pairs drawn
 once from the mix's ``pool_seed`` at the configuration's shapes, taken in
 an order drawn from the seed, a new order for each pass over the pool.
-Each solve goes through one jitted ``entropic_gw`` compiled during set-up
-and ends in ``block_until_ready``.  The window runs from the start of the
-first solve to the end of the first pass over the pool that ends after
-``seconds``, so every seed solves each pool problem equally often;
-``solve_s`` is the window over the number of solves.  A solve that
-returns a value that is not finite, or that stops at its outer cap short
-of the configuration's tolerance, has failed.  One solve, drawn from the
-seed by reservoir sampling over the window, is kept and checked against
-the reference afterwards.
+The configuration's geometry is ``bench/geometries/<type>.py``: the
+program solves on what its ``program`` returns, the reference on its
+dense ``distance``.  Each solve goes through one jitted ``entropic_gw``
+compiled during set-up and ends in ``block_until_ready``.  The window
+runs from the start of the first solve to the end of the first pass over
+the pool that ends after ``seconds``, so every seed solves each pool
+problem equally often; ``solve_s`` is the window over the number of
+solves.  A solve that returns a value that is not finite, or that stops
+at its outer cap short of the configuration's tolerance, has failed.  One
+solve, drawn from the seed by reservoir sampling over the window, is kept
+and checked against the reference afterwards.
 """
 from __future__ import annotations
 
 import math
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-from bench import generate, roofline
+from bench import generate, roofline, spec
 from bench.drivers import Check, Window, span
 from bench.reference import gw as ref
+
+BENCH = Path(__file__).resolve().parents[1]
 
 
 def _measure(key, n):
@@ -45,22 +50,21 @@ def pool_order(seed: int, pool: int):
         yield from (int(j) for j in r.permutation(pool))
 
 
-def _geometry(geo: dict):
-    from repro.core.grids import Grid2D
-
-    if geo["type"] != "grid2d":
-        raise ValueError(f"solve mixes run grid2d geometries, not "
-                         f"{geo['type']!r}")
-    side, k = int(geo["side"]), int(geo["k"])
-    return Grid2D(side, 1.0 / (side - 1), k)
+def geometry(geo: dict):
+    """The module of ``bench/geometries/<type>.py`` beside this driver's
+    own ``bench/`` (the run's checkout) for a configuration's geometry."""
+    kind = geo["type"]
+    return spec.load_file(BENCH / "geometries" / f"{kind}.py",
+                          f"solve geometry of type {kind!r}")
 
 
-class SolveDriver:
+class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int):
         from repro.core.gw import GWConfig, entropic_gw
 
         self.config = config
-        self.grid = _geometry(config["geometry"])
+        self.geometry = geometry(config["geometry"])
+        self.grid = self.geometry.program(config["geometry"])
         self.n = self.grid.size
         self.solver = config["solver"]
         cfg = GWConfig(**self.solver)
@@ -117,8 +121,7 @@ class SolveDriver:
         solve's optimum, its marginals, and its outer step count."""
         i, j, res = self.kept
         mu, nu = self.measures[j]
-        geo = self.config["geometry"]
-        d = ref.grid2d_distance(int(geo["side"]), int(geo["k"]))
+        d = self.geometry.distance(self.config["geometry"])
         sol = ref.solve(d, d, mu, nu, ref.Settings.of(self.solver))
         energy = ref.value(d, d, mu, nu, res.plan)
         row, col = ref.marginal_gaps(res.plan, mu, nu)

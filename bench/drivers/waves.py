@@ -17,7 +17,7 @@ others.  A fresh engine serves the window, so its plan cache starts
 empty; warm-up runs on another engine, with problems of its own.
 
 Afterwards every answer of one wave of the window, drawn from the seed,
-is checked against the reference (see `WavesDriver.check`): a wave holds
+is checked against the reference (see `Driver.check`): a wave holds
 every size, and its runs start, refill and repack their slot batches as
 the window's do.
 """
@@ -124,7 +124,7 @@ def _drain(lanes: int, left: int) -> list:
     return [1] * (lanes - left) + [2] * left
 
 
-class WavesDriver:
+class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int):
         from repro.core.gw import GWConfig
         from repro.serve.engine import GWServeConfig

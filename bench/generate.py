@@ -1,7 +1,8 @@
 """The one generator behind every traffic mix.
 
 A mix is a JSON file of parameters under ``bench/traffic/``; its ``kind``
-says which of the two shapes of work it asks for:
+names the driver that runs it, ``bench/drivers/<kind>.py``.  The kinds
+here:
 
 ``solve``  one-shot solves back to back over a pool of measure pairs
            drawn once from the mix's ``pool_seed``, in an order drawn from

@@ -9,6 +9,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import os
 import sys
 import tempfile
 import time
@@ -57,7 +58,8 @@ def compile_seconds() -> dict:
 @dataclasses.dataclass
 class Run:
     """What a per-layer reader reads: the window's counters, its length,
-    the chip's peaks and, in a traced run, the reduced trace."""
+    the chip's peaks and, in a traced run, the reduced trace
+    (`bench.trace.Reduced`, its ``scope_seconds`` filled in)."""
 
     cell: spec.Cell
     peaks: dict
@@ -80,6 +82,26 @@ def _peak_bytes(devs):
              for d in devs]
     peaks = [p for p in peaks if p is not None]
     return max(peaks) if peaks else None
+
+
+def read_trace(directory: str, log=sys.stderr):
+    """The reduced trace (`bench.trace.Reduced`) of the one ``.xplane.pb``
+    under ``directory``, with the seconds of each ``gw.*`` scope over the
+    same ``bench.window`` span."""
+    from bench import trace, xplane
+
+    path = trace.find_xplane(directory)
+    t0 = time.perf_counter()
+    loaded = trace.load(path)
+    reduced = trace.reduce(loaded)
+    t1 = time.perf_counter()
+    reduced.scope_seconds = xplane.scope_seconds(path, *trace.window(loaded))
+    scopes = " ".join(f"{k}={v!r}" for k, v in sorted(
+        reduced.scope_seconds.items(), key=lambda kv: -kv[1]))
+    print(f"trace: {os.path.getsize(path)} bytes reduced in {t1 - t0:.3f} s "
+          f"and split by scope in {time.perf_counter() - t1:.3f} s; "
+          f"device seconds by scope: {scopes}", file=log, flush=True)
+    return reduced
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -106,7 +128,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     with (jax.default_matmul_precision(matmul) if matmul
           else contextlib.nullcontext()):
         built = compiles()
-        drv = driver(cell.traffic["kind"])(config, cell.traffic, seed)
+        drv = driver(cell.traffic["kind"], root)(config, cell.traffic, seed)
         setup_s = time.perf_counter() - t0
         built = compiles() - built
         spent = " ".join(f"{k}={v:.3f}"
@@ -122,8 +144,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 with jax.profiler.trace(tdir):
                     with span("window"):
                         win = drv.window(seconds)
-                from bench import trace as trace_mod
-                reduced = trace_mod.reduce_dir(tdir)
+                reduced = read_trace(tdir, log)
         else:
             with span("window"):
                 win = drv.window(seconds)

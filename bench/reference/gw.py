@@ -2,10 +2,11 @@
 
 Written from the method's description (arXiv 2404.08970 §2.1, with the
 annealed, tolerance-controlled driver that the served and one-shot solves
-state): dense distance matrices, dense products at
-``Precision.HIGHEST``, XLA's logsumexp, and host-side loops.  It imports
-nothing of the code under test, so a change to the solver cannot move the
-yardstick.
+state): dense distance matrices (a solve geometry's ``distance``, from
+``bench/geometries/<type>.py``, or `sqeuclidean_distance`), dense
+products at ``Precision.HIGHEST``, XLA's logsumexp, and host-side loops.
+It imports nothing of the code under test, so a change to the solver
+cannot move the yardstick.
 
 One outer step at stage ``s`` with ``eps_s = max(eps, eps_init * decay**s)``:
 
@@ -66,17 +67,6 @@ class Solution:
     outer_iters: int
     inner_iters: int
     marginal_err: float
-
-
-@partial(jax.jit, static_argnames=("side", "k"))
-def grid2d_distance(side: int, k: int) -> jax.Array:
-    """(side², side²) Manhattan distances to the power k between the points
-    of the unit square's side × side grid, rows in row-major order."""
-    h = 1.0 / (side - 1)
-    a = jnp.repeat(jnp.arange(side, dtype=F32), side) * h
-    b = jnp.tile(jnp.arange(side, dtype=F32), side) * h
-    d = jnp.abs(a[:, None] - a[None, :]) + jnp.abs(b[:, None] - b[None, :])
-    return d ** k
 
 
 @jax.jit

@@ -5,9 +5,16 @@ per-layer metric sits in a file of its own, found from ``BENCHMARK.json``:
 
     configuration  the ``file`` of its ``configs`` entry (JSON)
     traffic mix    ``bench/traffic/<traffic>.json``
+    driver         ``bench/drivers/<the mix's kind>.py``, with ``Driver``
+    solve geometry ``bench/geometries/<the geometry's type>.py``, with
+                   ``program(geo)`` and ``distance(geo)``
     metric reader  ``bench/metrics/<metric name>.py``, with ``read(run)``
+    CPU cut        ``bench/tests/cuts/<configuration>.json`` and
+                   ``bench/tests/cuts/traffic/<traffic>.json``
 
-so a new cell, mix or metric is new files and new entries, never an edit.
+so a new cell, mix, configuration, shape of work or metric is new files
+and new entries, never an edit.  Each module is loaded by path from the
+run's own checkout.
 """
 from __future__ import annotations
 
@@ -15,6 +22,7 @@ import copy
 import dataclasses
 import importlib.util
 import json
+import re
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -37,16 +45,24 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(bench: Path, name: str):
-    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = bench / "metrics" / f"{name}.py"
+def load_file(path: Path, what: str):
+    """The module at ``path``, loaded by path (so from the run's checkout,
+    whichever ``bench`` package is imported); ``what`` names it in the
+    error raised when there is no such file."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no {what}: {path} does not exist")
+    stem = re.sub(r"\W", "_", path.stem)
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    if spec is None:
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+        f"bench_{path.parent.name}_{stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench: Path, name: str):
+    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
+    return load_file(bench / "metrics" / f"{name}.py",
+                     f"reader for metric {name!r}").read
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:

@@ -7,6 +7,9 @@ import numpy as np
 import pytest
 
 from bench.reference import gw as ref
+from bench.spec import BENCH, load_file
+
+grid2d = load_file(BENCH / "geometries" / "grid2d.py", "grid2d geometry")
 
 GRID_SOLVER = {"eps": 0.004, "eps_init": 0.05, "anneal_decay": 0.5,
                "outer_iters": 8, "sinkhorn_iters": 200, "sinkhorn_chunk": 25,
@@ -22,12 +25,12 @@ def _measure(key, n):
 
 
 def test_grid_distance_is_the_manhattan_distance():
-    d = np.asarray(ref.grid2d_distance(3, 1))
+    d = np.asarray(grid2d.distance({"side": 3, "k": 1}))
     h = 0.5
     assert d[0, 8] == pytest.approx(4 * h)      # (0,0) to (2,2)
     assert d[1, 3] == pytest.approx(2 * h)      # (0,1) to (1,0)
     np.testing.assert_array_equal(d, d.T)
-    d2 = np.asarray(ref.grid2d_distance(3, 2))
+    d2 = np.asarray(grid2d.distance({"side": 3, "k": 2}))
     np.testing.assert_allclose(d2, d ** 2, rtol=1e-6)
 
 
@@ -46,10 +49,12 @@ def test_reference_walks_entropic_gw_on_a_small_grid(side):
     k = jax.random.PRNGKey(side)
     mu = _measure(jax.random.fold_in(k, 0), side * side)
     nu = _measure(jax.random.fold_in(k, 1), side * side)
-    grid = Grid2D(side, 1.0 / (side - 1), 1)
+    geo = {"type": "grid2d", "side": side, "k": 1}
+    grid = grid2d.program(geo)
+    assert grid == Grid2D(side, 1.0 / (side - 1), 1)
     cfg = GWConfig(**GRID_SOLVER, backend="cumsum", sinkhorn_backend="xla")
     got = jax.jit(lambda a, b: entropic_gw(grid, grid, a, b, cfg))(mu, nu)
-    d = ref.grid2d_distance(side, 1)
+    d = grid2d.distance(geo)
     want = ref.solve(d, d, mu, nu, ref.Settings.of(GRID_SOLVER))
     assert int(got.info.outer_iters) == want.outer_iters
     assert int(got.info.inner_iters) == want.inner_iters

@@ -1,7 +1,10 @@
 """Peaks, the byte counts behind both rooflines, the per-layer readers, the
-generator, and the command's refusal to run without a TPU."""
+lookups of drivers, geometries and CPU cuts by name, the generator, and
+the command's refusal to run without a TPU."""
 import json
 import os
+import re
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -103,6 +106,43 @@ def test_every_metric_of_benchmark_json_has_a_reader_and_a_cell():
         c = load_cell(w["name"])
         e2e = {m["name"] for m in c.end_to_end}
         assert "setup_s" in e2e and len(e2e) >= 2 and c.per_layer
+
+
+def test_drivers_and_geometries_are_found_by_name_in_the_root(tmp_path):
+    """Each kind and geometry type is a file; an unknown one is an error
+    that names the file looked for, in the root the run was given."""
+    from bench.drivers import driver
+    from bench.drivers.solve import geometry
+
+    assert driver("solve", ROOT).__name__ == "Driver"
+    assert callable(geometry({"type": "grid2d"}).distance)
+    missing = tmp_path / "bench" / "drivers" / "solve.py"
+    with pytest.raises(FileNotFoundError, match=re.escape(str(missing))):
+        driver("solve", tmp_path)
+    with pytest.raises(FileNotFoundError, match="drivers/open_loop.py"):
+        driver("open_loop", ROOT)
+    with pytest.raises(FileNotFoundError, match="geometries/torus.py"):
+        geometry({"type": "torus"})
+    with pytest.raises(FileNotFoundError, match="metrics/nothing.solve.py"):
+        load_reader(ROOT / "bench", "nothing.solve")
+
+
+def test_every_configuration_has_a_cpu_cut(tmp_path):
+    """A configuration's whole-run tests run at the sizes of its cut under
+    bench/tests/cuts; without one the tiny checkout is refused, naming it,
+    so that no test runs a configuration at its full size on the CPU."""
+    from bench.tests._whole_run import missing_cuts, write_tiny
+
+    assert missing_cuts() == []
+    bj = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bj["configs"].append(dict(bj["configs"][0], name="uncut"))
+    src = tmp_path / "src"
+    shutil.copytree(ROOT / "bench", src / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (src / "BENCHMARK.json").write_text(json.dumps(bj))
+    assert missing_cuts(src) == ["uncut"]
+    with pytest.raises(FileNotFoundError, match="uncut"):
+        write_tiny(tmp_path / "tiny", src)
 
 
 def _waves(seed, mix="zipf", stream=generate.WINDOW, n=4, **cut):
@@ -210,8 +250,6 @@ def test_run_without_a_tpu_exits_nonzero_and_prints_no_result():
 
 
 def test_run_from_the_benchmark_files_alone_exits_nonzero(tmp_path):
-    import shutil
-
     shutil.copytree(ROOT / "bench", tmp_path / "bench")
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")

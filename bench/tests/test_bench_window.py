@@ -28,8 +28,8 @@ def _count_window_builds(monkeypatch) -> list:
     built = []
     real = harness.driver
 
-    def driver(kind):
-        cls = real(kind)
+    def driver(kind, root):
+        cls = real(kind, root)
 
         class Counted(cls):
             def window(self, seconds):

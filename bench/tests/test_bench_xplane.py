@@ -5,12 +5,13 @@
 ``scoped.xplane.pb`` was recorded on one TPU v5e: one solve of the
 ``grid2d-128`` configuration cut to a 16×16 grid, with Pallas half-steps
 and a fixed ``SCOPED_OUTER`` outer steps of 50 sweeps each (tol 0), of
-pool problem 0 (seed 7), run through `bench.drivers.solve.SolveDriver`
-once to warm up and once more inside one ``bench.window`` span under
-``jax.profiler.trace``.
+pool problem 0 (seed 7), run through the ``Driver`` of
+``bench/drivers/solve.py`` once to warm up and once more inside one
+``bench.window`` span under ``jax.profiler.trace``.
 """
 import dataclasses
 import importlib.util
+import io
 import math
 import os
 import shutil
@@ -19,7 +20,7 @@ from pathlib import Path
 import pytest
 
 from bench import trace, xplane
-from bench.harness import Run
+from bench.harness import Run, read_trace
 from bench.spec import ROOT, load_cell, load_reader
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -33,6 +34,9 @@ READERS = {"gw_sinkhorn_row_roofline.solve": "%gw_sinkhorn_row",
 SMALL_REDUCED = {
     "busy_s": 0.000106484, "window_s": 0.06546945600000001,
     "idle_share": 0.9983735316206079,
+    "op_seconds": {"%fusion": 0.00010635500000000001,
+                   "%copy-start": 1.08e-07,
+                   "%copy-done": 2.1000000000000003e-08},
     "device_ops": [["%fusion", 0.00010635500000000001],
                    ["%copy-start", 1.08e-07],
                    ["%copy-done", 2.1000000000000003e-08]],
@@ -45,7 +49,8 @@ SMALL_REDUCED = {
                   ["bench.window", 0.00015097300000000002],
                   ["bench.window", 0.000149325],
                   ["bench.window", 0.0001419],
-                  ["bench.window", 2e-09]]}
+                  ["bench.window", 2e-09]],
+    "scope_seconds": None}
 
 
 def _xplane_pb2():
@@ -154,12 +159,18 @@ def _trace_dir(tmp_path, path):
 def test_reduce_of_the_small_trace_reads_as_before(tmp_path):
     t = trace.load(str(SMALL))
     assert dataclasses.asdict(trace.reduce(t)) == SMALL_REDUCED
-    r = trace.reduce_dir(_trace_dir(tmp_path, SMALL))
-    assert dataclasses.asdict(r) == SMALL_REDUCED
+    log = io.StringIO()
+    r = read_trace(_trace_dir(tmp_path, SMALL), log)
+    assert "split by scope in" in log.getvalue()
+    got = dataclasses.asdict(r)
     # a program without scopes: all of its device time is unscoped
-    (lo, hi), = [(s, e) for s, e, n in t.spans if n == trace.WINDOW_SPAN]
-    assert xplane.scope_seconds(str(SMALL), lo, hi) == \
+    assert got.pop("scope_seconds") == \
         {xplane.UNSCOPED: pytest.approx(r.busy_s)}
+    assert got == {k: v for k, v in SMALL_REDUCED.items()
+                   if k != "scope_seconds"}
+    (lo, hi), = [(s, e) for s, e, n in t.spans if n == trace.WINDOW_SPAN]
+    assert trace.window(t) == (lo, hi)
+    assert xplane.scope_seconds(str(SMALL), lo, hi) == r.scope_seconds
 
 
 def _leaf_seconds(t: trace.Trace, lo, hi) -> float:
@@ -189,6 +200,26 @@ def test_scopes_split_the_leaf_device_time_of_the_window(scoped):
     assert xplane.UNSCOPED not in scopes
 
 
+def test_op_seconds_hold_every_leaf_op_and_the_breakdown_its_top(scoped):
+    t, lo, hi, _ = scoped
+    r = trace.reduce(t)
+    assert len(r.op_seconds) > trace.TOP
+    assert sum(r.op_seconds.values()) == pytest.approx(
+        _leaf_seconds(t, lo, hi), rel=1e-12)
+    assert r.device_ops == [[n, s] for n, s in r.op_seconds.items()][
+        :trace.TOP]
+    assert list(r.op_seconds.values()) == sorted(r.op_seconds.values(),
+                                                 reverse=True)
+    assert r.op_seconds == dict(trace.reduce(t, top=1000).device_ops)
+
+
+def test_harness_reads_the_scopes_of_the_window(scoped, tmp_path):
+    t, lo, hi, scopes = scoped
+    r = read_trace(_trace_dir(tmp_path, SCOPED), io.StringIO())
+    assert r.scope_seconds == scopes
+    assert r.op_seconds == trace.reduce(t).op_seconds
+
+
 def test_scoped_trace_names_the_half_step_kernels(scoped):
     t, lo, hi, _ = scoped
     r = trace.reduce(t, top=1000)
@@ -212,7 +243,7 @@ def test_kernel_readers_read_the_scoped_trace(scoped, tiny_root, tmp_path):
         assert read(untraced) is None
     # a program whose kernels have other names (the small trace's) reads
     # nothing
-    r = trace.reduce_dir(_trace_dir(tmp_path, SMALL))
+    r = read_trace(_trace_dir(tmp_path, SMALL), io.StringIO())
     bare = Run(cell, peaks, r.window_s, counters, r)
     for name in READERS:
         assert load_reader(tiny_root / "bench", name)(bare) is None
@@ -222,13 +253,13 @@ def test_kernel_readers_read_the_scoped_trace(scoped, tiny_root, tmp_path):
 def test_kernel_readers_count_one_pass_per_sweep(name):
     """Two solves of 60 and 25 sweeps on a 4×4 grid (a (16, 16) plan):
     each half-step kernel reads the cost once a sweep, 85 passes, in the
-    second its breakdown gives it."""
+    second its ``op_seconds`` give it."""
     class Cell:
         config = {"geometry": {"side": 4}}
 
     class Reduced:
-        device_ops = [["%reduce_window_sum", 4.0], [READERS[name], 1.0],
-                      ["%copy", 0.5]]
+        op_seconds = {"%reduce_window_sum": 4.0, READERS[name]: 1.0,
+                      "%copy": 0.5}
     read = load_reader(ROOT / "bench", name)
     peaks = {"hbm_bytes_per_s": 1.0}
     run = Run(Cell, peaks, 3.0, {"inner_iters": [60, 25]}, Reduced)
@@ -238,7 +269,7 @@ def test_kernel_readers_count_one_pass_per_sweep(name):
     assert read(Run(Cell, peaks, 3.0, {"inner_iters": [60]}, None)) is None
 
     class Renamed:
-        device_ops = [["%_sinkhorn_row_update_pallas", 1.0],
-                      ["%_sinkhorn_col_update_pallas", 1.0]]
+        op_seconds = {"%_sinkhorn_row_update_pallas": 1.0,
+                      "%_sinkhorn_col_update_pallas": 1.0}
     assert read(Run(Cell, peaks, 3.0, {"inner_iters": [60]}, Renamed)) \
         is None

@@ -1,5 +1,5 @@
-"""Reduce a profiler trace (``.xplane.pb``) to device busy time, idle share
-and the run's ``breakdown``.
+"""Reduce a profiler trace (``.xplane.pb``) to device busy time, idle share,
+the seconds of every device op and the run's ``breakdown``.
 
 Device work is the events of the ``XLA Ops`` line of each ``/device:TPU:<i>``
 plane.  Busy time is the union of their intervals inside the traced window,
@@ -117,19 +117,41 @@ def label(t: float, spans: list) -> str:
 
 @dataclasses.dataclass
 class Reduced:
-    busy_s: float          # mean over the traced devices
+    """What the per-layer readers of a traced run read, all of it within
+    the ``bench.window`` span."""
+
+    #: seconds in which some operation ran, the mean over the traced devices
+    busy_s: float
+    #: seconds of the window
     window_s: float
-    idle_share: float      # 1 − busy / window, in [0, 1]
-    device_ops: list       # [[op name, seconds]] of leaf events, most first
-    idle_gaps: list        # [[span name, seconds]] longest first
+    #: 1 − busy / window, in [0, 1]
+    idle_share: float
+    #: {op name: seconds} of every leaf event, summed over the traced
+    #: devices, most first
+    op_seconds: dict
+    #: the first ``top`` of ``op_seconds`` as [[op name, seconds]]: the
+    #: result line's ``breakdown``
+    device_ops: list
+    #: [[span name, seconds]] of the idle gaps of every traced device,
+    #: longest first, the first ``top``
+    idle_gaps: list
+    #: {``gw.*`` scope: seconds} of every leaf event, summed over the traced
+    #: devices (`bench.xplane.scope_seconds`); None until read from the
+    #: trace's file
+    scope_seconds: dict | None = None
 
 
-def reduce(trace: Trace, top: int = TOP) -> Reduced:
+def window(trace: Trace) -> tuple:
+    """(start, end) of the one ``bench.window`` span."""
     windows = [(s, e) for s, e, n in trace.spans if n == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"expected one {WINDOW_SPAN} span, "
                          f"found {len(windows)}")
-    lo, hi = windows[0]
+    return windows[0]
+
+
+def reduce(trace: Trace, top: int = TOP) -> Reduced:
+    lo, hi = window(trace)
     if not any(trace.device_ops.values()):
         raise ValueError("the trace holds no device operation")
     busy_ns, op_time, idle = [], defaultdict(float), []
@@ -141,17 +163,15 @@ def reduce(trace: Trace, top: int = TOP) -> Reduced:
             if d > 0:
                 op_time[name] += d
         idle.extend(gaps(busy, lo, hi))
-    window = hi - lo
+    window_ns = hi - lo
     busy = sum(busy_ns) / len(busy_ns)
-    ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:top]
+    ops = {n: t * 1e-9
+           for n, t in sorted(op_time.items(), key=lambda kv: -kv[1])}
     longest = sorted(idle, key=lambda g: g[0] - g[1])[:top]
     return Reduced(
-        busy_s=busy * 1e-9, window_s=window * 1e-9,
-        idle_share=1.0 - busy / window,
-        device_ops=[[n, t * 1e-9] for n, t in ops],
+        busy_s=busy * 1e-9, window_s=window_ns * 1e-9,
+        idle_share=1.0 - busy / window_ns,
+        op_seconds=ops,
+        device_ops=[[n, t] for n, t in list(ops.items())[:top]],
         idle_gaps=[[label((s + e) / 2, trace.spans), (e - s) * 1e-9]
                    for s, e in longest])
-
-
-def reduce_dir(directory: str, top: int = TOP) -> Reduced:
-    return reduce(load(find_xplane(directory)), top)

@@ -31,8 +31,9 @@ the innermost ``gw.`` component of each op's ``tf_op`` (its ``op_name``,
 which ``jax.named_scope`` writes).  An op that XLA made after lowering
 carries no ``op_name`` of its own; it takes the name of its nearest named
 neighbour in the HLO module the trace carries (`op_names`).  Time in no
-``gw.`` scope is ``"unscoped"``.  The result line does not carry the split
-yet: `bench.trace.reduce_dir` would have to call it.
+``gw.`` scope is ``"unscoped"``.  The harness hands the split of a traced
+window to the per-layer readers (`bench.trace.Reduced.scope_seconds`);
+the result line does not carry it.
 """
 from __future__ import annotations
 
